@@ -8,8 +8,8 @@ GraphMat engine as the mesh grows.  For each device count D we lower the
 distributed PageRank superstep on a (D×1) host mesh, run the trip-count-
 aware HLO analyzer, and report the roofline-projected speedup on TPU-v5e
 constants (197 TF bf16, 819 GB/s HBM, 50 GB/s ICI) plus the measured
-per-device balance.  Run standalone (it re-execs itself with the fake-device
-env var):
+per-device balance.  The projection runs in a child process on fake CPU
+devices (``JAX_PLATFORMS=cpu``).  Run standalone:
 
   PYTHONPATH=src python benchmarks/bench_scaling.py
 """
@@ -71,12 +71,13 @@ print(json.dumps(out))
 
 
 def main() -> list:
-  env = dict(os.environ)
-  env["PYTHONPATH"] = "src"
+  # The child is an HLO projection on fake host devices: it must never open
+  # an accelerator, which its parent may already hold.
+  env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
   res = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                        capture_output=True, text=True, timeout=900)
   if res.returncode != 0:
-    return [f"scaling/ERROR,0.0,{res.stderr.strip()[-200:]}"]
+    raise RuntimeError(f"scaling child failed: {res.stderr.strip()[-2000:]}")
   data = json.loads(res.stdout.strip().splitlines()[-1])
   rows = []
   t1 = None

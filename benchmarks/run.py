@@ -1,6 +1,7 @@
 """Benchmark driver — one section per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows.
+Prints ``name,us_per_call,derived`` CSV rows and exits non-zero if any
+section raised (its row is then ``<section>/ERROR``).
 
   PYTHONPATH=src python -m benchmarks.run [--quick]
 """
@@ -18,6 +19,8 @@ def main(argv=None) -> int:
   ap.add_argument("--skip-scaling", action="store_true")
   args = ap.parse_args(argv)
   scale = 10 if args.quick else 12
+  from repro.compile_cache import enable_compile_cache
+  enable_compile_cache()
 
   print("name,us_per_call,derived")
   sections = []

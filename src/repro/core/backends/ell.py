@@ -1,4 +1,4 @@
-"""ELL backend: degree-sorted packed rows, axis-1 reduce (+ COO spill)."""
+"""ELL backend: degree-sorted slot-major packed rows, reduce over slots (+ COO spill)."""
 
 from __future__ import annotations
 

@@ -29,7 +29,9 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Any, Callable, Dict, Hashable, List, Optional, Sequence
+import warnings
+from typing import (Any, Callable, Dict, Hashable, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -104,7 +106,7 @@ def compute_stats(graph) -> GraphStats:
     if graph.spill is not None:
       spill = int(np.asarray(graph.spill.emask).sum())
     nnz = packed + spill
-    in_deg = mask.sum(axis=1)[np.asarray(graph.row_of) < graph.n]
+    in_deg = mask.sum(axis=0)[np.asarray(graph.row_of) < graph.n]
     mean, mx, cv, hub = _degree_stats(in_deg.astype(np.float64))
     return GraphStats(
         "ell", graph.n, nnz, nnz / max(graph.n, 1), mx, cv, hub,
@@ -167,6 +169,8 @@ class Planner:
     ell_efficiency_floor: minimum ELL slot fill for the Pallas kernel to
       beat the jnp ELL path (below it the kernel mostly reduces padding).
     cache: memo for :meth:`autotune` winners (fingerprint-keyed).
+    failures: ``(plan, repr(exception))`` of every autotune candidate that
+      failed to compile or run (each also raises a RuntimeWarning).
   """
 
   skew_threshold: float = 4.0
@@ -174,6 +178,7 @@ class Planner:
   max_tiles: int = 64
   ell_efficiency_floor: float = 0.25
   cache: PlanCache = dataclasses.field(default_factory=PlanCache)
+  failures: List[Tuple[Plan, str]] = dataclasses.field(default_factory=list)
 
   # -- heuristic planning ----------------------------------------------------
 
@@ -259,16 +264,19 @@ class Planner:
     cands = list(candidates) if candidates is not None else self.candidates(
         graph, program, q)
 
-    def runner(plan: Plan):
+    # The graph and initial state are jit arguments, not baked-in constants.
+    def runner(g, prop, active, plan: Plan):
       if batched:
-        return engine.run_batched(graph, program, init_prop, init_active,
+        return engine.run_batched(g, program, prop, active,
                                   max_iters=num_iters, backend=plan)
-      return engine.run_fixed_iters(graph, program, init_prop, init_active,
-                                    num_iters, backend=plan)
+      return engine.run_fixed_iters(g, program, prop, active, num_iters,
+                                    backend=plan)
 
+    run_jit = jax.jit(runner, static_argnames="plan")
     best_plan, best_t = None, float("inf")
     for plan in cands:
-      fn = jax.jit(lambda p=plan: runner(p))
+      def fn():
+        return run_jit(graph, init_prop, init_active, plan=plan)
       try:
         jax.block_until_ready(fn())  # compile + warm
         times = []
@@ -277,8 +285,11 @@ class Planner:
           jax.block_until_ready(fn())
           times.append(timer() - t0)
         t = float(np.median(times))
-      except Exception:
-        continue  # a candidate that cannot execute this program loses
+      except Exception as e:  # the candidate loses, but never silently
+        self.failures.append((plan, repr(e)))
+        warnings.warn(f"autotune candidate {plan} failed: {e!r}",
+                      RuntimeWarning, stacklevel=2)
+        continue
       if t < best_t:
         best_plan, best_t = plan, t
     if best_plan is None:

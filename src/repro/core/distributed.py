@@ -84,8 +84,15 @@ class DistGraph:
 
 
 def partition_2d(src, dst, w=None, *, n: int, R: int, C: int,
-                 edge_dtype=jnp.float32) -> DistGraph:
-  """Host-side 2-D partitioner (numpy)."""
+                 edge_dtype=jnp.float32,
+                 mesh: Optional[Mesh] = None) -> DistGraph:
+  """Host-side 2-D partitioner (numpy).
+
+  With ``mesh``, block ``(i, j)`` is placed straight onto its device in the
+  runners' default layout (``P("data", "model")`` on the two block dims), so
+  no device ever holds the whole graph; without it the blocks land on the
+  default device.
+  """
   dt = np.dtype(edge_dtype)
   src, dst, w = graphlib._as_np_edges(src, dst, w, n, dt)
   n_pad = int(np.ceil(n / (R * C))) * (R * C)  # divisible by both R and C
@@ -97,15 +104,13 @@ def partition_2d(src, dst, w=None, *, n: int, R: int, C: int,
   # Sort by (block_i, block_j, local dst) so each block is dst-sorted.
   order = np.lexsort((ldst, bj, bi))
   bi, bj, ldst, lsrc, w = bi[order], bj[order], ldst[order], lsrc[order], w[order]
-  counts = np.zeros((R, C), np.int64)
-  np.add.at(counts, (bi, bj), 1)
-  cap = max(int(counts.max()), 1)
+  # Position of each edge within its block.
+  flat = bi * C + bj
+  cap = max(int(np.bincount(flat, minlength=R * C).max()), 1)
   bsrc = np.zeros((R, C, cap), np.int32)
   bdst = np.full((R, C, cap), max(nr - 1, 0), np.int32)  # keep dst sorted-ish
   bw = np.zeros((R, C, cap), dt)
   bmask = np.zeros((R, C, cap), bool)
-  # Position of each edge within its block.
-  flat = bi * C + bj
   # edges already sorted by (bi,bj); position = index - first index of block
   first = np.searchsorted(flat, flat)
   pos = np.arange(flat.shape[0]) - first
@@ -113,9 +118,13 @@ def partition_2d(src, dst, w=None, *, n: int, R: int, C: int,
   bdst[bi, bj, pos] = ldst
   bw[bi, bj, pos] = w
   bmask[bi, bj, pos] = True
-  return DistGraph(n=n, n_pad=n_pad, R=R, C=C,
-                   src=jnp.asarray(bsrc), dst=jnp.asarray(bdst),
-                   w=jnp.asarray(bw), emask=jnp.asarray(bmask))
+  if mesh is None:
+    put = jnp.asarray
+  else:
+    put = partial(jax.device_put,
+                  device=NamedSharding(mesh, P("data", "model")))
+  return DistGraph(n=n, n_pad=n_pad, R=R, C=C, src=put(bsrc), dst=put(bdst),
+                   w=put(bw), emask=put(bmask))
 
 
 def _semiring_axis_reduce(y: PyTree, recv: Array, axis_name: str,
@@ -223,7 +232,7 @@ def run_graph_program_2d(
     return jax.tree_util.tree_map(
         lambda x: jax.lax.with_sharding_constraint(x, sharding), tree)
 
-  def superstep(state: EngineState) -> EngineState:
+  def superstep(g: DistGraph, state: EngineState) -> EngineState:
     msg = jax.vmap(program.send_message)(state.prop)
     # Reshard sources column-wise (the superstep-boundary transpose).
     msg = constrain(msg, col_sharding)
@@ -237,15 +246,17 @@ def run_graph_program_2d(
     return EngineState(new_prop, changed, state.iteration + 1,
                        jnp.sum(changed.astype(jnp.int32)))
 
+  # The graph is a jit argument, so its blocks stay where partition_2d put
+  # them instead of being baked into the program as constants.
   @jax.jit
-  def loop(prop0, active0):
+  def loop(g, prop0, active0):
     state = EngineState(prop0, active0, jnp.int32(0),
                         jnp.sum(active0.astype(jnp.int32)))
     return jax.lax.while_loop(
         lambda s: jnp.logical_and(s.iteration < max_iters, s.num_active > 0),
-        superstep, state)
+        partial(superstep, g), state)
 
-  return loop(init_prop, init_active)
+  return loop(g, init_prop, init_active)
 
 
 def run_graph_program_2d_batched(
@@ -277,7 +288,8 @@ def run_graph_program_2d_batched(
     return jax.tree_util.tree_map(
         lambda x: jax.lax.with_sharding_constraint(x, sharding), tree)
 
-  def superstep(state: BatchedEngineState) -> BatchedEngineState:
+  def superstep(g: DistGraph, state: BatchedEngineState
+                ) -> BatchedEngineState:
     live = jnp.logical_not(state.done)
     msg = jax.vmap(program.send_message)(state.prop)
     lane_mask = jnp.logical_and(state.active, live[None, :])
@@ -306,11 +318,11 @@ def run_graph_program_2d_batched(
         iters=state.iters + live.astype(jnp.int32))
 
   @jax.jit
-  def loop(prop0, active0):
+  def loop(g, prop0, active0):  # the graph is an argument, as above
     state = init_batched_state(prop0, active0)
     return jax.lax.while_loop(
         lambda s: jnp.logical_and(s.iteration < max_iters,
                                   jnp.logical_not(jnp.all(s.done))),
-        superstep, state)
+        partial(superstep, g), state)
 
-  return loop(init_prop, init_active)
+  return loop(g, init_prop, init_active)

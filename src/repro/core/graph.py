@@ -10,8 +10,9 @@ wants *static shapes and unit-stride loads*.  We therefore provide:
   becomes tiling the edge array into equal-size tiles: perfectly balanced by
   construction.  Backend: gather + segmented reduce.
 * :class:`EllGraph` — degree-sorted ELLPACK rows (SELL-σ-style permutation)
-  with a fixed slot width per degree bucket and a COO spill for hub rows.
-  This is the VMEM-tileable format the Pallas kernel consumes.
+  with a fixed slot width and a COO spill for hub rows, stored slot-major
+  (``[width, n_pad]``) so packed rows lie along the TPU's lane axis.  This
+  is the VMEM-tileable format the Pallas kernel consumes.
 * ``dense_adjacency`` — small-graph oracle.
 
 All containers are registered pytrees of ``jax.Array``s with static metadata,
@@ -50,7 +51,7 @@ class CooGraph:
 
   n: int                 # static: number of vertices
   src: Array             # int32[capacity]
-  dst: Array             # int32[capacity], non-decreasing over real edges
+  dst: Array             # int32[capacity], non-decreasing (padding: n-1)
   w: Array               # edge values [capacity] (ones if unweighted)
   emask: Array           # bool[capacity]
   out_deg: Array         # int32[n]
@@ -83,33 +84,37 @@ class EllGraph:
   within a slot block is bounded; rows with in-degree > ``width`` spill their
   excess edges into a COO tail that is processed by the segment backend.
 
-  ``cols[r, s]`` is the *source* vertex of the s-th incoming edge of packed
-  row r; ``row_of[r]`` maps packed row -> vertex id; ``packed_of[v]`` is the
-  inverse permutation.
+  ``cols[s, r]`` is the *source* vertex of the s-th incoming edge of packed
+  row r (slot-major: rows are the minor, lane-dense axis); ``row_of[r]``
+  maps packed row -> vertex id; ``packed_of[v]`` is the inverse
+  permutation.  Rows are sorted by in-degree, so slot s holds edges only in
+  packed rows ``[0, slot_rows[s])``, a non-increasing extent the Pallas
+  kernel uses to skip padding (None: every row).
   """
 
   n: int                 # static: number of vertices
   width: int             # static: ELL slot width
-  cols: Array            # int32[n_pad, width]  (source vertex ids)
-  vals: Array            # [n_pad, width]       (edge values)
-  mask: Array            # bool[n_pad, width]
+  cols: Array            # int32[width, n_pad]  (source vertex ids)
+  vals: Array            # [width, n_pad]       (edge values)
+  mask: Array            # bool[width, n_pad]
   row_of: Array          # int32[n_pad]  packed row -> vertex id
   packed_of: Array       # int32[n]      vertex id -> packed row
   spill: Optional[CooGraph]  # hub-row excess edges (or None)
+  slot_rows: Optional[Tuple[int, ...]] = None  # static: row extent per slot
 
   def tree_flatten(self):
     children = (self.cols, self.vals, self.mask, self.row_of, self.packed_of,
                 self.spill)
-    return children, (self.n, self.width)
+    return children, (self.n, self.width, self.slot_rows)
 
   @classmethod
   def tree_unflatten(cls, aux, children):
-    n, width = aux
-    return cls(n, width, *children)
+    n, width, slot_rows = aux
+    return cls(n, width, *children, slot_rows=slot_rows)
 
   @property
   def n_pad(self) -> int:
-    return int(self.cols.shape[0])
+    return int(self.cols.shape[1])
 
 
 @jax.tree_util.register_pytree_node_class
@@ -186,14 +191,15 @@ def build_coo(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
 
 
 def build_ell(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
-              width: Optional[int] = None, row_block: int = 8,
+              width: Optional[int] = None, row_block: int = 128,
               spill_frac_cap: float = 1.0) -> EllGraph:
   """Build a degree-sorted :class:`EllGraph` (+ spill) from host edges.
 
   Args:
     width: ELL slot width.  Default: the 95th-percentile in-degree rounded up
       to a multiple of 8 — hub rows beyond it spill to COO (hybrid format).
-    row_block: pad packed rows to a multiple of this (Pallas tile divisor).
+    row_block: pad packed rows to a multiple of this (the Pallas row tile
+      is a multiple of the 128-wide lane axis).
     spill_frac_cap: sanity cap on the fraction of edges allowed to spill.
   """
   dt = np.dtype(edge_dtype)
@@ -211,9 +217,9 @@ def build_ell(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
   inv[perm] = np.arange(n, dtype=np.int32)                    # vid -> packed
 
   n_pad = int(np.ceil(n / row_block)) * row_block
-  cols = np.full((n_pad, width), PAD, np.int32)
-  vals = np.zeros((n_pad, width), dt)
-  mask = np.zeros((n_pad, width), bool)
+  cols = np.full((width, n_pad), PAD, np.int32)
+  vals = np.zeros((width, n_pad), dt)
+  mask = np.zeros((width, n_pad), bool)
 
   # Slot position of each edge within its destination row.
   order = np.argsort(dst, kind="stable")
@@ -225,9 +231,9 @@ def build_ell(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
     slot = np.zeros(0, np.int64)
   fits = slot < width
   r = inv[s_dst[fits]]
-  cols[r, slot[fits]] = s_src[fits]
-  vals[r, slot[fits]] = s_w[fits]
-  mask[r, slot[fits]] = True
+  cols[slot[fits], r] = s_src[fits]
+  vals[slot[fits], r] = s_w[fits]
+  mask[slot[fits], r] = True
 
   spill_src, spill_dst, spill_w = s_src[~fits], s_dst[~fits], s_w[~fits]
   total = max(src.shape[0], 1)
@@ -236,15 +242,22 @@ def build_ell(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
   spill = None
   if spill_src.shape[0]:
     spill = build_coo(spill_src, spill_dst, spill_w, n=n, edge_dtype=dt)
+  # Rows with in-degree > s, rounded up to whole row blocks.
+  deeper = n - np.cumsum(np.bincount(np.minimum(in_deg, width),
+                                     minlength=width + 1))[:width]
+  slot_rows = tuple(min(n_pad, -(-int(c) // row_block) * row_block)
+                    for c in deeper)
 
   # Padded packed rows map to vertex `n` (out of bounds): the un-permute
-  # scatter uses mode="drop" so they vanish; gathers clip and are masked.
+  # gathers through `packed_of`, which never selects them; gathers through
+  # `row_of` clip and are masked.
   row_of = np.concatenate(
       [perm, np.full(n_pad - n, n, np.int32)]) if n_pad > n else perm
   return EllGraph(
       n=n, width=int(width),
       cols=jnp.asarray(cols), vals=jnp.asarray(vals), mask=jnp.asarray(mask),
-      row_of=jnp.asarray(row_of), packed_of=jnp.asarray(inv), spill=spill)
+      row_of=jnp.asarray(row_of), packed_of=jnp.asarray(inv), spill=spill,
+      slot_rows=slot_rows)
 
 
 def dense_adjacency(src, dst, w=None, *, n: int,
@@ -272,10 +285,10 @@ def coo_from_ell(g: EllGraph) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
   vals = np.asarray(g.vals)
   mask = np.asarray(g.mask)
   row_of = np.asarray(g.row_of)
-  rr, ss = np.nonzero(mask)
-  src = cols[rr, ss]
+  ss, rr = np.nonzero(mask)
+  src = cols[ss, rr]
   dst = row_of[rr]
-  w = vals[rr, ss]
+  w = vals[ss, rr]
   if g.spill is not None:
     em = np.asarray(g.spill.emask)
     src = np.concatenate([src, np.asarray(g.spill.src)[em]])

@@ -14,9 +14,9 @@ Backends:
   * ``spmv_coo``   — gather + segmented reduce over a dst-sorted edge list
                      (scatter fast-paths for add/min/max/any; associative
                      segmented scan for generic monoids).
-  * ``spmv_ell``   — degree-sorted ELL rows: gather + axis-1 reduce — the
-                     layout consumed by the Pallas kernel; hub spill edges
-                     are folded in via the COO path.
+  * ``spmv_ell``   — degree-sorted slot-major ELL: gather + reduce over
+                     slots — the layout consumed by the Pallas kernel; hub
+                     spill edges are folded in via the COO path.
 """
 
 from __future__ import annotations
@@ -145,20 +145,19 @@ def _segment_reduce_fast(r: PyTree, dst: Array, n: int, kind: str,
                          ident: PyTree) -> PyTree:
   """Scatter-based segment reduce for monoids with an ``.at[]`` fast path."""
   # Identity leaves are full arrays shaped like r; take their scalar fill.
+  # ``dst`` is non-decreasing (the CooGraph invariant); saying so lets XLA
+  # compile a large scatter in seconds instead of tens of seconds.
   def scatter(leaf, ident_leaf):
     fill = ident_leaf.reshape(-1)[0]
     out = jnp.full((n,) + leaf.shape[1:], fill, leaf.dtype)
     upd = out.at[dst]
+    kw = dict(mode="drop", indices_are_sorted=True)
     if kind == "add":
-      return upd.add(leaf, mode="drop")
-    if kind == "min":
-      return upd.min(leaf, mode="drop")
-    if kind == "max":
-      return upd.max(leaf, mode="drop")
-    if kind == "any":
-      return upd.max(leaf, mode="drop")
-    if kind == "all":
-      return upd.min(leaf, mode="drop")
+      return upd.add(leaf, **kw)
+    if kind in ("min", "all"):
+      return upd.min(leaf, **kw)
+    if kind in ("max", "any"):
+      return upd.max(leaf, **kw)
     raise ValueError(kind)
   return jax.tree_util.tree_map(scatter, r, ident)
 
@@ -212,61 +211,57 @@ def spmv_coo(g: graphlib.CooGraph, msg: PyTree, active: Array,
     y = _segment_reduce_scan(r, g.dst, g.n, program.reduce_fn(), ident)
   if not with_recv:
     return y, None
-  recv = jnp.zeros((g.n,), jnp.bool_).at[g.dst].max(valid, mode="drop")
+  recv = jnp.zeros((g.n,), jnp.bool_).at[g.dst].max(
+      valid, mode="drop", indices_are_sorted=True)
   return y, recv
 
 
 # ---------------------------------------------------------------------------
-# ELL: gather + axis-1 reduce (+ spill via COO)
+# ELL: gather + reduce over slots (+ spill via COO)
 # ---------------------------------------------------------------------------
 
 
 def _ell_packed_compute(g: graphlib.EllGraph, msg: PyTree, active: Array,
                         dst_prop: PyTree, program: GraphProgram):
-  """Per-packed-row (y_packed, recv_packed) on the ELL block."""
-  m = _tree_gather(msg, g.cols)                      # [n_pad, W, ...]
+  """Per-packed-row (y_packed, recv_packed) on the slot-major ELL block."""
+  m = _tree_gather(msg, g.cols)                      # [W, n_pad, ...]
   valid = g.mask & active[g.cols]
   if program.process_reads_dst:
     safe_rows = jnp.minimum(g.row_of, g.n - 1)
     dp = _tree_gather(dst_prop, safe_rows)           # [n_pad, ...]
     dp = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(
-            x[:, None], x.shape[:1] + (g.width,) + x.shape[1:]), dp)
+        lambda x: jnp.broadcast_to(x[None], (g.width,) + x.shape), dp)
   else:
     # process_message ignores dst_prop — feed a broadcast dummy row.
     dp = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(
-            x[:1][:, None], (g.cols.shape[0], g.width) + x.shape[1:]),
-        dst_prop)
-  r = _vmap_process(program, 2)(m, g.vals, dp)       # [n_pad, W, ...]
+            x[:1], (g.width, g.n_pad) + x.shape[1:]), dst_prop)
+  r = _vmap_process(program, 2)(m, g.vals, dp)       # [W, n_pad, ...]
   ident = program.identity_like(r)
   r = _tree_where(valid, r, ident)
   if program.reduce_kind in _SCATTER_FAST:
     axis_red = _AXIS_RED[program.reduce_kind]
-    y_packed = jax.tree_util.tree_map(lambda x: axis_red(x, axis=1), r)
+    y_packed = jax.tree_util.tree_map(lambda x: axis_red(x, axis=0), r)
   else:
-    y_packed = _axis_tree_reduce(r, program.reduce_fn(), ident, axis=1)
-  recv_packed = jnp.any(valid, axis=1)
-  return y_packed, recv_packed, ident
+    y_packed = _axis_tree_reduce(r, program.reduce_fn(), ident, axis=0)
+  recv_packed = jnp.any(valid, axis=0)
+  return y_packed, recv_packed
 
 
-def _unpermute(g: graphlib.EllGraph, y_packed: PyTree, recv_packed: Array,
-               ident: PyTree) -> Tuple[PyTree, Array]:
-  def scatter(leaf, ident_leaf):
-    fill = ident_leaf.reshape(-1)[0]
-    out = jnp.full((g.n,) + leaf.shape[1:], fill, leaf.dtype)
-    return out.at[g.row_of].set(leaf, mode="drop")
-  y = jax.tree_util.tree_map(scatter, y_packed, ident)
-  recv = jnp.zeros((g.n,), bool).at[g.row_of].set(recv_packed, mode="drop")
-  return y, recv
+def _unpermute(g: graphlib.EllGraph, y_packed: PyTree, recv_packed: Array
+               ) -> Tuple[PyTree, Array]:
+  """Packed rows back to vertex order: a gather through ``packed_of`` (every
+  vertex owns a packed row)."""
+  y = _tree_gather(y_packed, g.packed_of)
+  return y, recv_packed[g.packed_of]
 
 
 def spmv_ell(g: graphlib.EllGraph, msg: PyTree, active: Array,
              dst_prop: PyTree, program: GraphProgram,
              with_recv: bool = True) -> Tuple[PyTree, Optional[Array]]:
-  y_packed, recv_packed, ident = _ell_packed_compute(
+  y_packed, recv_packed = _ell_packed_compute(
       g, msg, active, dst_prop, program)
-  y, recv = _unpermute(g, y_packed, recv_packed, ident)
+  y, recv = _unpermute(g, y_packed, recv_packed)
   if g.spill is not None:
     y_s, recv_s = spmv_coo(g.spill, msg, active, dst_prop, program)
     red = program.reduce_fn()
@@ -350,13 +345,14 @@ def spmv_coo_tiled(g: graphlib.CooGraph, msg: PyTree, active: Array,
 
   kind = program.reduce_kind
 
-  def scatter(acc, idx, leaf):
+  def scatter(acc, idx, leaf):  # a tile of dst-sorted edges
     upd = acc.at[idx]
+    kw = dict(mode="drop", indices_are_sorted=True)
     if kind == "add":
-      return upd.add(leaf, mode="drop")
+      return upd.add(leaf, **kw)
     if kind in ("min", "all"):
-      return upd.min(leaf, mode="drop")
-    return upd.max(leaf, mode="drop")  # max / any
+      return upd.min(leaf, **kw)
+    return upd.max(leaf, **kw)  # max / any
 
   def body(i, carry):
     y, recv = carry
@@ -372,7 +368,7 @@ def spmv_coo_tiled(g: graphlib.CooGraph, msg: PyTree, active: Array,
     y = jax.tree_util.tree_map(
         lambda acc, leaf: scatter(acc, d_t, leaf), y, r)
     if recv is not None:
-      recv = recv.at[d_t].max(valid, mode="drop")
+      recv = recv.at[d_t].max(valid, mode="drop", indices_are_sorted=True)
     return y, recv
 
   y, recv = jax.lax.fori_loop(0, t, body, (y0, recv0))
@@ -403,11 +399,15 @@ def spmv(graph, msg: PyTree, active: Array, dst_prop: PyTree,
 
 def _pallas_eligible(g: graphlib.EllGraph, msg: PyTree, dst_prop: PyTree,
                      program: GraphProgram) -> bool:
-  # The Pallas kernel handles single-leaf scalar or 1-vector messages with
-  # fast-path reductions; everything else uses the jnp ELL backend.
+  # The Pallas kernel handles single-leaf scalar messages, or [n, Q] lanes of
+  # a lanewise program, with add/min/max reductions; everything else uses
+  # the jnp ELL backend.
   leaves = jax.tree_util.tree_leaves(msg)
+  if len(leaves) != 1 or program.reduce_kind not in ("add", "min", "max"):
+    return False
+  ndim = leaves[0].ndim
+  if ndim > 2 or (ndim == 2 and not program.lanewise):
+    return False
   dp_leaves = jax.tree_util.tree_leaves(dst_prop)
-  dp_ok = (not program.process_reads_dst) or (
-      len(dp_leaves) == 1 and dp_leaves[0].ndim <= 2)
-  return (len(leaves) == 1 and leaves[0].ndim <= 2 and dp_ok
-          and program.reduce_kind in ("add", "min", "max"))
+  return (not program.process_reads_dst) or (
+      len(dp_leaves) == 1 and dp_leaves[0].ndim <= ndim)

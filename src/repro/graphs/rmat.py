@@ -32,24 +32,28 @@ def rmat_edges(scale: int, edge_factor: int = 16,
   a, b, c = abc
   n_edges = (1 << scale) * edge_factor
   rng = np.random.default_rng(seed)
-  src = np.zeros(n_edges, np.int64)
-  dst = np.zeros(n_edges, np.int64)
+  src = np.zeros(n_edges, np.int32)
+  dst = np.zeros(n_edges, np.int32)
+  # Per-level buffers, reused: at Graph500 scale 22 each is 67M entries.
+  u = np.empty(n_edges)
+  src_bit = np.empty(n_edges, bool)
+  dst_bit = np.empty(n_edges, bool)
   for level in range(scale):
     # Jitter quadrant probabilities per level.
     f = 1.0 + noise * (2 * rng.random(4) - 1.0)
     pa, pb, pc, pd = a * f[0], b * f[1], c * f[2], (1 - a - b - c) * f[3]
     norm = pa + pb + pc + pd
     pa, pb, pc = pa / norm, pb / norm, pc / norm
-    u = rng.random(n_edges)
-    src_bit = (u >= pa + pb).astype(np.int64)
-    # P(dst_bit=1 | src_bit) — quadrant decomposition.
-    dst_bit = np.where(
-        src_bit == 0,
-        (u >= pa).astype(np.int64),                      # within top: B region
-        (u >= pa + pb + pc).astype(np.int64))            # within bottom: D
-    src |= src_bit << level
-    dst |= dst_bit << level
-  return src.astype(np.int32), dst.astype(np.int32)
+    rng.random(out=u)
+    # Quadrants A | B | C | D split [0, 1) at pa, pa+pb, pa+pb+pc: the src
+    # bit is set in C and D, the dst bit in B and D.
+    np.greater_equal(u, pa + pb, out=src_bit)
+    np.greater_equal(u, pa, out=dst_bit)
+    dst_bit &= ~src_bit
+    dst_bit |= u >= pa + pb + pc
+    src |= src_bit.astype(np.int32) << level
+    dst |= dst_bit.astype(np.int32) << level
+  return src, dst
 
 
 def bipartite_ratings(num_users: int, num_items: int, ratings_per_user: int,

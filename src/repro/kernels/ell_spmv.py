@@ -1,33 +1,39 @@
-"""Pallas TPU kernel: generalized blocked-ELL SpMV (the paper's hot loop).
+"""Pallas TPU kernel: generalized ELL SpMV (the paper's hot loop).
 
 The paper spends >80% of runtime in Algorithm 1 (generalized SpMV) and
 optimizes it with cache-resident bitvectors, ``-ipo`` inlining of the user
 functions, and load-balanced partitions.  The TPU translation:
 
-* **Layout** — degree-sorted ELL: ``cols/vals/mask[n_pad, W]``.  Fixed row
-  width ⇒ the per-row reduction is a masked axis-1 reduce over a VMEM tile —
-  unit-stride, VPU-vectorized, no pointer chasing.
-* **Tiling** — grid ``(n_pad/BR, W/BW)``; each step owns a ``(BR, BW)`` tile
-  of the ELL arrays in VMEM plus the whole message vector (the analogue of
-  the paper's L3-resident bitvector+value array: after 2-D partitioning the
-  per-device source slice is small, so ``msg`` fits VMEM).  The slot axis is
-  innermost so the output tile ``y[BR]`` stays resident while partial slot
-  tiles accumulate into it.
+* **Layout** — slot-major, degree-sorted ELL: ``cols/vals/mask[W, n_pad]``.
+  Packed rows (destination vertices) lie along the 128-wide lane axis, so
+  every tile is lane-dense whatever the slot width W, and the per-row
+  reduction over slots is an elementwise combine across sublanes.
+* **Gather** — ``msg[cols]`` is gathered by XLA outside the kernel: Mosaic
+  has no general in-kernel vector gather, and keeping ``msg`` resident in
+  VMEM does not scale past a few million sources.  The kernel fuses what
+  follows the gather — PROCESS_MESSAGE, the validity mask and the REDUCE
+  over slots — so per-edge results never round-trip through HBM.
+* **Chunks** — a ``[n_src, K]`` payload (K batched queries of a *lanewise*
+  program) runs in blocks of BQ lanes, and the slots in chunks: one gather
+  and one kernel call per (chunk, block), so each gathered ``[BQ, CW, R]``
+  temporary stays within :data:`GATHER_BYTES` at any graph size.
+* **Row extents** — rows are sorted by in-degree, so slot s holds edges only
+  in the first ``slot_rows[s]`` packed rows.  A chunk gathers and reduces
+  only those R rows, and chunks widen as R shrinks: on a power-law graph
+  this skips most of the ELL's padding.
+* **Tiling** — each call's grid runs over row tiles of BR packed rows; a
+  tile reduces its CW slots in VMEM into ``y[BQ, 1, BR]``.
 * **Inlining** — the user's PROCESS_MESSAGE/REDUCE are traced straight into
   the kernel body (the ``-ipo`` effect, by construction).
-* **Messages** — scalar or K-vector payloads; K-vector turns each tile into
-  an (BR·BW, K) gather + reduce, the CF/SpMM case.
-
-Validated with ``interpret=True`` on CPU (per-kernel allclose vs ``ref.py``);
-on real TPUs the gather of ``msg`` rows uses VMEM dynamic indexing — for very
-large per-device sources a scalar-prefetch (``PrefetchScalarGridSpec``)
-column-tiled variant would be the next step (documented, not required here).
+* **Interpret mode** is chosen from the platform the program is lowered for
+  (``lax.platform_dependent``): interpreted on CPU, compiled by Mosaic on
+  TPU.  ``interpret=True/False`` forces one.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +43,12 @@ Array = jax.Array
 
 _AXIS_RED = {"add": jnp.sum, "min": jnp.min, "max": jnp.max}
 _COMBINE = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+# Bytes of one gathered message chunk [BQ, CW, n_pad] (an HBM temporary; the
+# TPU gather also stages a flat copy of its indices of the same size).
+GATHER_BYTES = 1 << 28
+# Target bytes of one message tile [BQ, CW, BR] in VMEM.
+TILE_BYTES = 1 << 20
 
 
 def _identity_scalar(kind: str, dtype):
@@ -51,50 +63,19 @@ def _identity_scalar(kind: str, dtype):
   raise ValueError(kind)
 
 
-def _kernel(cols_ref, vals_ref, mask_ref, msg_ref, act_ref, dprop_ref,
-            y_ref, recv_ref, *, process, reduce_kind, out_dtype,
-            tiled_q: bool = False):
-  """One (BR, BW) ELL tile; the slot axis (innermost grid dim) accumulates
-  into y.  With ``tiled_q`` the grid is (rows, query tiles, slot tiles) and
-  each step sees a (n_src, BQ) message column tile — the multi-query SpMM
-  path (lanewise programs only)."""
-  j = pl.program_id(2) if tiled_q else pl.program_id(1)
-
-  @pl.when(j == 0)
-  def _init():
-    y_ref[...] = jnp.full(
-        y_ref.shape, _identity_scalar(reduce_kind, out_dtype), out_dtype)
-
-  # recv is query-independent; its (BR,) block is shared by all query tiles,
-  # so initialize it only on the very first visit.
-  first_recv = (j == 0 if not tiled_q
-                else jnp.logical_and(j == 0, pl.program_id(1) == 0))
-
-  @pl.when(first_recv)
-  def _init_recv():
-    recv_ref[...] = jnp.zeros(recv_ref.shape, jnp.int8)
-
-  cols = cols_ref[...]                       # [BR, BW] source ids (local)
-  vals = vals_ref[...]                       # [BR, BW]
-  mask = mask_ref[...] != 0                  # [BR, BW]
-  msg = msg_ref[...]                         # [n_src, K] resident slice
-  act = act_ref[...]                         # [n_src] int8
-  dprop = dprop_ref[...]                     # [BR, Kd]
-
-  m = jnp.take(msg, cols, axis=0)            # [BR, BW, K] gather
-  a = jnp.take(act, cols, axis=0) != 0       # [BR, BW]
-  valid = jnp.logical_and(mask, a)
-
-  dp = jnp.broadcast_to(dprop[:, None, :],
-                        (dprop.shape[0], cols.shape[1], dprop.shape[1]))
-  r = process(m, vals, dp)                   # [BR, BW, K_out]
-  ident = _identity_scalar(reduce_kind, out_dtype)
-  r = jnp.where(valid[..., None], r, ident)
-
-  partial_y = _AXIS_RED[reduce_kind](r, axis=1)            # [BR, K_out]
-  y_ref[...] = _COMBINE[reduce_kind](y_ref[...], partial_y)
-  partial_recv = jnp.any(valid, axis=1).astype(jnp.int8)   # [BR]
-  recv_ref[...] = jnp.maximum(recv_ref[...], partial_recv)
+def _kernel(m_ref, e_ref, v_ref, *refs, process, reduce_kind, out_dtype):
+  """One row tile of one slot chunk: y[BQ, 1, BR] = REDUCE over the CW slots
+  of ``where(valid, process(m, e, d), identity)``."""
+  d_ref, y_ref = refs if len(refs) == 2 else (None, refs[0])
+  m = m_ref[...]                                     # [BQ, CW, BR]
+  e = jnp.broadcast_to(e_ref[...][None], m.shape)    # edge values
+  d = (jnp.broadcast_to(d_ref[...], m.shape) if d_ref is not None
+       else jnp.zeros(m.shape, m.dtype))             # dst property
+  valid = v_ref[...] != 0                            # [CW, BR]
+  r = process(m, e, d).astype(out_dtype)
+  r = jnp.where(jnp.broadcast_to(valid[None], r.shape), r,
+                _identity_scalar(reduce_kind, out_dtype))
+  y_ref[...] = _AXIS_RED[reduce_kind](r, axis=1, keepdims=True)
 
 
 def _pick_block(total: int, target: int, multiple: int) -> int:
@@ -104,110 +85,142 @@ def _pick_block(total: int, target: int, multiple: int) -> int:
   for cand in range(multiple, min(target, total) + 1, multiple):
     if total % cand == 0:
       best = cand
-  return best if total % best == 0 else total
+  return best
+
+
+def _lane_block(k: int, cap: int) -> int:
+  """Lanes per call: all K if they fit ``cap``, else the largest multiple of 8
+  dividing K that fits, else 1.  (Blocks of 2-7 lanes make XLA's TPU gather
+  compile for tens of seconds at millions of rows.)"""
+  if k <= cap:
+    return k
+  best = _pick_block(k, cap, 8)
+  return best if best <= cap else 1
+
+
+def _call(mg, vals, valid, dp, *, process, reduce_kind, out_dtype, br,
+          interpret):
+  bq, cw, n_pad = mg.shape
+  in_specs = [pl.BlockSpec((bq, cw, br), lambda i: (0, 0, i)),
+              pl.BlockSpec((cw, br), lambda i: (0, i)),
+              pl.BlockSpec((cw, br), lambda i: (0, i))]
+  args = [mg, vals, valid]
+  if dp is not None:
+    in_specs.append(pl.BlockSpec((dp.shape[0], 1, br), lambda i: (0, 0, i)))
+    args.append(dp)
+  kern = functools.partial(_kernel, process=process, reduce_kind=reduce_kind,
+                           out_dtype=out_dtype)
+  return pl.pallas_call(
+      kern,
+      grid=(n_pad // br,),
+      in_specs=in_specs,
+      out_specs=pl.BlockSpec((bq, 1, br), lambda i: (0, 0, i)),
+      out_shape=jax.ShapeDtypeStruct((bq, 1, n_pad), out_dtype),
+      interpret=interpret,
+      name="ell_spmv",
+  )(*args)
 
 
 def ell_spmv_pallas(
     cols: Array, vals: Array, mask: Array, msg: Array, active: Array,
-    dprop: Array, *, process: Callable, reduce_kind: str,
-    out_dtype=None, out_k: Optional[int] = None,
+    dprop: Optional[Array] = None, *, process: Callable, reduce_kind: str,
     block_rows: Optional[int] = None, block_slots: Optional[int] = None,
     block_queries: Optional[int] = None,
+    slot_rows: Optional[Sequence[int]] = None,
     interpret: Optional[bool] = None) -> Tuple[Array, Array]:
   """Generalized ELL SpMV / multi-query SpMM.
 
   Args:
-    cols: int32[n_pad, W] packed source indices.
-    vals: [n_pad, W] edge values.
-    mask: int8/bool[n_pad, W] slot validity.
-    msg:  [n_src, K] message payloads (K=1 for scalar programs; K=Q for
-      batched multi-query lanewise programs).
-    active: int8/bool[n_src].
-    dprop: [n_pad, Kd] destination properties, already row-permuted.
-    process: (m[...,K], e[...], d[...,Kd]) -> r[..., K_out]; traced inline.
+    cols: int32[W, n_pad] source index of each (slot, packed row).
+    vals: [W, n_pad] edge values.
+    mask: bool[W, n_pad] slot validity.
+    msg:  [n_src] scalar payloads, or [n_src, K] lanes of a lanewise program
+      (K batched queries; lanes never mix).
+    active: bool[n_src] source frontier.
+    dprop: None, or destination properties in packed row order: [n_pad], or
+      [n_pad, K] per lane.
+    process: elementwise ``(m, e, d) -> r`` on same-shaped arrays — the
+      per-edge PROCESS_MESSAGE of a vertex program; traced inline.
     reduce_kind: add | min | max.
-    block_queries: tile the message/output K axis into (n_src, BQ) column
-      tiles — the multi-query SpMM path.  Only valid for *lanewise*
-      processes (no cross-K mixing; requires K_out == K): each grid step
-      then reuses one gathered ELL tile across a BQ-wide query tile.
+    block_rows: rows per kernel tile (BR; a multiple of 128 on TPU, or
+      n_pad).
+    block_slots: slots per gather + kernel call (CW; the last chunk may be
+      narrower).
+    block_queries: lanes per gather + kernel call (BQ divides K).
+    slot_rows: non-increasing static row extents: slot s holds edges only
+      in packed rows ``[0, slot_rows[s])`` (None: in all rows).
+    interpret: force the Pallas interpreter on/off (default: by platform).
   Returns:
-    (y[n_pad, K_out], recv int8[n_pad]).
+    (y with msg's trailing shape over n_pad rows, recv bool[n_pad]).
   """
-  n_pad, w = cols.shape
-  n_src, k = msg.shape
-  if out_dtype is None or out_k is None:
-    probe = jax.eval_shape(
-        lambda m, e, d: process(m, e, d),
-        jax.ShapeDtypeStruct((1, 1, k), msg.dtype),
-        jax.ShapeDtypeStruct((1, 1), vals.dtype),
-        jax.ShapeDtypeStruct((1, 1, dprop.shape[1]), dprop.dtype))
-    out_dtype = out_dtype or probe.dtype
-    out_k = out_k or probe.shape[-1]
+  w, n_pad = cols.shape
+  lanes = msg if msg.ndim == 2 else msg[:, None]          # [n_src, K]
+  n_src, k = lanes.shape
+  dl = None if dprop is None else (
+      dprop if dprop.ndim == 2 else dprop[:, None])       # [n_pad, Kd]
+  out_dtype = jax.eval_shape(
+      process, jax.ShapeDtypeStruct((), msg.dtype),
+      jax.ShapeDtypeStruct((), vals.dtype),
+      jax.ShapeDtypeStruct((), msg.dtype if dl is None else dl.dtype)).dtype
+
+  rows = [n_pad] * w if slot_rows is None else list(slot_rows)
+  isz = lanes.dtype.itemsize
+  unit = block_rows or (128 if n_pad % 128 == 0 else n_pad)
+  assert n_pad % unit == 0, f"block_rows {unit} must divide n_pad={n_pad}"
+  budget = GATHER_BYTES // isz              # elements of one gathered chunk
+  bq = block_queries or _lane_block(k, max(1, budget // max(rows[0], 1)))
+  assert k % bq == 0, f"block_queries {bq} must divide K={k}"
+
+  # Greedy slot chunks: each as wide as the budget allows at its row extent
+  # (rounded up to the row unit); slots past the last nonempty one are skipped.
+  chunks, s0 = [], 0
+  while s0 < w and rows[s0] > 0:
+    r = min(n_pad, -(-rows[s0] // unit) * unit)
+    cw = block_slots or max(1, min(budget // (bq * r),
+                                   TILE_BYTES // (isz * bq * unit)))
+    cw = cw - cw % 8 if cw > 8 else cw      # sublane multiples compile faster
+    chunks.append((s0, min(w, s0 + cw), r))
+    s0 += cw
+
   if interpret is None:
-    interpret = jax.default_backend() != "tpu"
+    def kernel(*a, **kw):
+      return jax.lax.platform_dependent(
+          *a, cpu=functools.partial(_call, interpret=True, **kw),
+          tpu=functools.partial(_call, interpret=False, **kw))
+  else:
+    kernel = functools.partial(_call, interpret=interpret)
 
-  br = block_rows or _pick_block(n_pad, 256, 8)
-  bw = block_slots or _pick_block(w, 512, 8)
+  nb = k // bq
+  m_t = lanes.T.reshape(nb, bq, n_src)
+  per_lane_d = dl is not None and dl.shape[1] > 1
+  if per_lane_d:
+    assert dl.shape[1] == k, f"dprop lanes {dl.shape[1]} != K={k}"
+    d_t = dl.T.reshape(nb, bq, 1, n_pad)
+  else:
+    d_shared = None if dl is None else dl.T[:, None, :]   # [1, 1, n_pad]
 
-  if block_queries is not None:
-    assert out_k == k, (
-        "block_queries requires a lanewise process (K_out == K); got "
-        f"K={k} K_out={out_k}")
-    bq = min(block_queries, k)
-    assert k % bq == 0, f"block_queries {bq} must divide K={k}"
-    # Grid order (rows, query tiles, slot tiles): the slot axis is innermost
-    # so each y[BR, BQ] tile accumulates across consecutive steps while the
-    # (n_src, BQ) message column tile stays VMEM-resident.
-    grid = (n_pad // br, k // bq, w // bw)
-    kern = functools.partial(
-        _kernel, process=process, reduce_kind=reduce_kind,
-        out_dtype=out_dtype, tiled_q=True)
-    y, recv = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, bw), lambda i, q, j: (i, j)),    # cols
-            pl.BlockSpec((br, bw), lambda i, q, j: (i, j)),    # vals
-            pl.BlockSpec((br, bw), lambda i, q, j: (i, j)),    # mask
-            pl.BlockSpec((n_src, bq), lambda i, q, j: (0, q)),  # msg column
-            pl.BlockSpec((n_src,), lambda i, q, j: (0,)),      # active
-            pl.BlockSpec((br, dprop.shape[1]),
-                         lambda i, q, j: (i, 0)),              # dprop
-        ],
-        out_specs=[
-            pl.BlockSpec((br, bq), lambda i, q, j: (i, q)),
-            pl.BlockSpec((br,), lambda i, q, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, k), out_dtype),
-            jax.ShapeDtypeStruct((n_pad,), jnp.int8),
-        ],
-        interpret=interpret,
-    )(cols, vals, mask.astype(jnp.int8), msg, active.astype(jnp.int8), dprop)
-    return y, recv
+  y = jnp.full((nb, bq, 1, n_pad), _identity_scalar(reduce_kind, out_dtype),
+               out_dtype)
+  recv = jnp.zeros((n_pad,), bool)
+  for s0, s1, r in chunks:
+    c, e, msk = (a[s0:s1, :r] for a in (cols, vals, mask))   # [CW, R]
+    valid = jnp.logical_and(msk, active[c])
+    br = block_rows or _pick_block(
+        r, max(unit, TILE_BYTES // (isz * bq * (s1 - s0))), unit)
+    call = functools.partial(kernel, process=process, reduce_kind=reduce_kind,
+                             out_dtype=out_dtype, br=br)
 
-  grid = (n_pad // br, w // bw)
-  kern = functools.partial(
-      _kernel, process=process, reduce_kind=reduce_kind, out_dtype=out_dtype)
-  y, recv = pl.pallas_call(
-      kern,
-      grid=grid,
-      in_specs=[
-          pl.BlockSpec((br, bw), lambda i, j: (i, j)),      # cols
-          pl.BlockSpec((br, bw), lambda i, j: (i, j)),      # vals
-          pl.BlockSpec((br, bw), lambda i, j: (i, j)),      # mask
-          pl.BlockSpec((n_src, k), lambda i, j: (0, 0)),    # msg (resident)
-          pl.BlockSpec((n_src,), lambda i, j: (0,)),        # active
-          pl.BlockSpec((br, dprop.shape[1]), lambda i, j: (i, 0)),  # dprop
-      ],
-      out_specs=[
-          pl.BlockSpec((br, out_k), lambda i, j: (i, 0)),
-          pl.BlockSpec((br,), lambda i, j: (i,)),
-      ],
-      out_shape=[
-          jax.ShapeDtypeStruct((n_pad, out_k), out_dtype),
-          jax.ShapeDtypeStruct((n_pad,), jnp.int8),
-      ],
-      interpret=interpret,
-  )(cols, vals, mask.astype(jnp.int8), msg, active.astype(jnp.int8), dprop)
-  return y, recv
+    def block(mb, db, c=c, e=e, v8=valid.astype(jnp.int8), call=call, r=r):
+      return call(jnp.take(mb, c, axis=1, mode="clip"), e, v8,
+                  None if db is None else db[..., :r])
+
+    if nb == 1:
+      part = block(m_t[0], d_t[0] if per_lane_d else d_shared)[None]
+    elif per_lane_d:
+      part = jax.lax.map(lambda a: block(*a), (m_t, d_t))
+    else:
+      part = jax.lax.map(lambda mb: block(mb, d_shared), m_t)
+    y = y.at[..., :r].set(_COMBINE[reduce_kind](y[..., :r], part))
+    recv = recv.at[:r].set(jnp.logical_or(recv[:r], jnp.any(valid, axis=0)))
+  y = y.reshape(k, n_pad).T                               # [n_pad, K]
+  return (y if msg.ndim == 2 else y[:, 0]), recv

@@ -16,16 +16,6 @@ Array = jax.Array
 PyTree = Any
 
 
-def _pick_query_block(q: int, target: int = 128) -> int:
-  """Largest divisor of ``q`` that is ≤ target (the lane-width-ish query
-  tile for the multi-query SpMM kernel path)."""
-  best = 1
-  for cand in range(1, min(target, q) + 1):
-    if q % cand == 0:
-      best = cand
-  return best
-
-
 def spmv_ell_pallas(g: graphlib.EllGraph, msg: PyTree, active: Array,
                     dst_prop: PyTree, program: GraphProgram,
                     **kernel_kwargs) -> Tuple[PyTree, Array]:
@@ -33,68 +23,28 @@ def spmv_ell_pallas(g: graphlib.EllGraph, msg: PyTree, active: Array,
   packed-ELL portion through the Pallas kernel (spill still folds via COO).
 
   Restrictions (enforced by ``spmv._pallas_eligible`` / asserted here):
-  single-leaf scalar-or-vector messages, fast-path reductions.
+  single-leaf scalar messages, or ``[n, Q]`` lanes of a lanewise program;
+  add/min/max reductions.
   """
   msg_leaves, msg_def = jax.tree_util.tree_flatten(msg)
   assert len(msg_leaves) == 1, "pallas path: single-leaf messages only"
   m = msg_leaves[0]
-  scalar_msg = m.ndim == 1
-  m2 = m[:, None] if scalar_msg else m
+  assert m.ndim == 1 or (m.ndim == 2 and program.lanewise), (
+      "pallas path: vector payloads must be lanes of a lanewise program")
 
+  dpp = None
   if program.process_reads_dst:
     dp_leaves = jax.tree_util.tree_leaves(dst_prop)
     assert len(dp_leaves) == 1, "pallas path: single-leaf dst_prop only"
-    dp = dp_leaves[0]
-    scalar_dp = dp.ndim == 1
-    dpp = dp[jnp.minimum(g.row_of, g.n - 1)]
-    dpp = dpp[:, None] if scalar_dp else dpp
-  else:
-    scalar_dp = True
-    dpp = jnp.zeros((g.cols.shape[0], 1), m2.dtype)
+    dpp = dp_leaves[0][jnp.minimum(g.row_of, g.n - 1)]
 
-  user_process = program.process_message
+  y_leaf, recv = ell_spmv_pallas(
+      g.cols, g.vals, g.mask, m, active, dpp,
+      process=program.process_message, reduce_kind=program.reduce_kind,
+      slot_rows=g.slot_rows, **kernel_kwargs)
+  y_packed = jax.tree_util.tree_unflatten(msg_def, [y_leaf])
 
-  # Probe the per-edge result rank: scalar results need a trailing unit dim
-  # inside the kernel and a squeeze outside.
-  probe = jax.eval_shape(
-      user_process,
-      jax.ShapeDtypeStruct(m.shape[1:], m.dtype),
-      jax.ShapeDtypeStruct((), g.vals.dtype),
-      jax.ShapeDtypeStruct(dpp.shape[1:] if not scalar_dp else (), dpp.dtype))
-  scalar_result = probe.ndim == 0
-
-  # Lanewise vector payloads (batched multi-query): the user's process is
-  # written per-lane (edge value and dst prop are scalars there), so give
-  # the edge/dst tiles a trailing broadcast axis against the K query lanes.
-  lanewise_vec = program.lanewise and not scalar_msg
-
-  def process(mb, eb, db):
-    # mb [BR, BW, K], eb [BR, BW], db [BR, BW, Kd] -> r [BR, BW, K_out]
-    if lanewise_vec:
-      r = user_process(mb, eb[..., None], db)
-      return r
-    m_in = mb[..., 0] if scalar_msg else mb
-    d_in = db[..., 0] if scalar_dp else db
-    r = user_process(m_in, eb, d_in)
-    return r[..., None] if scalar_result else r
-
-  # Lanewise vector payloads (the batched multi-query SpMM case): tile the
-  # query axis so each gathered ELL tile is reused across a query column
-  # tile instead of requiring the whole [n_src, Q] message block at once.
-  if ("block_queries" not in kernel_kwargs and program.lanewise
-      and not scalar_msg and not scalar_result
-      and not program.process_reads_dst):
-    kernel_kwargs["block_queries"] = _pick_query_block(m2.shape[1])
-
-  y2, recv_i8 = ell_spmv_pallas(
-      g.cols, g.vals, g.mask, m2, active, dpp,
-      process=process, reduce_kind=program.reduce_kind, **kernel_kwargs)
-  y_packed_leaf = y2[..., 0] if scalar_result else y2
-  y_packed = jax.tree_util.tree_unflatten(msg_def, [y_packed_leaf])
-  recv_packed = recv_i8 != 0
-
-  ident = program.identity_like(y_packed)
-  y, recv = _unpermute(g, y_packed, recv_packed, ident)
+  y, recv = _unpermute(g, y_packed, recv)
   if g.spill is not None:
     y_s, recv_s = spmv_coo(g.spill, msg, active, dst_prop, program)
     red = program.reduce_fn()

@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,21 +25,21 @@ def _identity_scalar(kind: str, dtype):
 
 
 def ell_spmv_ref(cols: Array, vals: Array, mask: Array, msg: Array,
-                 active: Array, dprop: Array, *, process: Callable,
-                 reduce_kind: str) -> Tuple[Array, Array]:
+                 active: Array, dprop: Optional[Array] = None, *,
+                 process: Callable, reduce_kind: str) -> Tuple[Array, Array]:
   """Oracle for :func:`repro.kernels.ell_spmv.ell_spmv_pallas`.
 
-  Same contract: msg [n_src, K], dprop [n_pad, Kd] pre-permuted, returns
-  (y [n_pad, K_out], recv int8[n_pad]).
+  Same contract: slot-major ``cols/vals/mask[W, n_pad]``, msg ``[n_src]`` or
+  ``[n_src, K]``, dprop None / ``[n_pad]`` / ``[n_pad, K]`` in packed row
+  order; returns (y over n_pad rows with msg's trailing shape, recv bool).
   """
-  n_pad, w = cols.shape
-  m = msg[cols]                                    # [n_pad, W, K]
-  a = active.astype(bool)[cols]
-  valid = mask.astype(bool) & a
-  dp = jnp.broadcast_to(dprop[:, None, :], (n_pad, w, dprop.shape[1]))
-  r = process(m, vals, dp)
+  m = msg[cols]                                    # [W, n_pad, (K)]
+  valid = mask.astype(bool) & active.astype(bool)[cols]
+  tail = (None,) * (msg.ndim - 1)
+  e = jnp.broadcast_to(vals[(...,) + tail], m.shape)
+  d = (jnp.zeros(m.shape, m.dtype) if dprop is None
+       else jnp.broadcast_to(dprop[None], m.shape))
+  r = process(m, e, d)
   ident = _identity_scalar(reduce_kind, r.dtype)
-  r = jnp.where(valid[..., None], r, ident)
-  y = _AXIS_RED[reduce_kind](r, axis=1)
-  recv = jnp.any(valid, axis=1).astype(jnp.int8)
-  return y, recv
+  r = jnp.where(valid[(...,) + tail], r, ident)
+  return _AXIS_RED[reduce_kind](r, axis=0), jnp.any(valid, axis=0)

@@ -365,10 +365,12 @@ class GraphQueryServer:
     active0 = jnp.zeros((n, self.num_slots), bool)
     self._state = init_batched_state(prop0, active0)
 
+    # The graph is a jit argument: its arrays stay device buffers instead of
+    # being baked into the compiled round as constants.
     self._round_fn = jax.jit(
-        lambda st: run_batched_rounds(self.graph, self.program, st,
-                                      self.steps_per_round,
-                                      backend=self.plan))
+        lambda g, st: run_batched_rounds(g, self.program, st,
+                                         self.steps_per_round,
+                                         backend=self.plan))
 
   def swap_graph(self, graph) -> Plan:
     """Replace the served graph with a new snapshot (idle servers only).
@@ -827,7 +829,7 @@ class GraphQueryServer:
         return False
       # The heavy SpMM rounds run outside the bookkeeping lock: submissions
       # land in the queue while the device crunches.
-      self._state, trace = self._round_fn(self._state)
+      self._state, trace = self._round_fn(self.graph, self._state)
       self.counters.inc("rounds")
       trace = np.asarray(trace)
       real = trace[trace >= 0]

@@ -345,7 +345,8 @@ def test_autotune_memoizes_by_fingerprint():
 
 
 def test_autotune_survives_broken_candidates():
-  """Candidates that cannot execute lose instead of raising."""
+  """Candidates that cannot execute lose instead of raising, and the
+  failure is recorded and warned about."""
 
   class Boom(B.Backend):
     name = "boom"
@@ -371,9 +372,13 @@ def test_autotune_survives_broken_candidates():
     active0 = jnp.zeros((n,), bool).at[0].set(True)
     planner = Planner()
     cands = [B.Plan(backend="boom"), B.Plan(backend="coo")]
-    p = planner.autotune(g, prog, prop0, active0, candidates=cands,
-                         repeats=1)
+    with pytest.warns(RuntimeWarning, match="boom"):
+      p = planner.autotune(g, prog, prop0, active0, candidates=cands,
+                           repeats=1)
     assert p == B.Plan(backend="coo")
+    # The failure is recorded with its exception, not swallowed.
+    assert [f[0] for f in planner.failures] == [B.Plan(backend="boom")]
+    assert "boom" in planner.failures[0][1]
   finally:
     B.unregister("boom")
 

@@ -8,14 +8,8 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
-# The children drive explicit-sharding meshes (jax.set_mesh /
-# AxisType.Auto); older jax (< 0.6) can't run them at all.
-pytestmark = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-    reason="needs jax.set_mesh / jax.sharding.AxisType (jax >= 0.6)")
 
 _CHILD = r"""
 import os

@@ -9,14 +9,8 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
-# The child compiles onto explicit-sharding production meshes (AxisType /
-# set_mesh era APIs); older jax (< 0.6) can't run it.
-pytestmark = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-    reason="needs jax.set_mesh / jax.sharding.AxisType (jax >= 0.6)")
 
 _CHILD = r"""
 import json

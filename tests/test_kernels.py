@@ -10,17 +10,18 @@ from repro.kernels.ref import ell_spmv_ref
 
 
 def make_ell(rng, n_pad, width, n_src, dtype):
-  cols = rng.integers(0, n_src, (n_pad, width)).astype(np.int32)
-  vals = rng.uniform(0.1, 2.0, (n_pad, width)).astype(dtype)
-  mask = rng.uniform(size=(n_pad, width)) > 0.3
+  """Slot-major ELL arrays ``[width, n_pad]``."""
+  cols = rng.integers(0, n_src, (width, n_pad)).astype(np.int32)
+  vals = rng.uniform(0.1, 2.0, (width, n_pad)).astype(dtype)
+  mask = rng.uniform(size=(width, n_pad)) > 0.3
   return jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(mask)
 
 
 PROCS = {
-    "min_plus": (lambda m, e, d: m + e[..., None], "min"),
-    "plus_times": (lambda m, e, d: m * e[..., None], "add"),
-    "max_times": (lambda m, e, d: m * e[..., None], "max"),
-    "plus_dst": (lambda m, e, d: (e[..., None] - m * d) * m, "add"),
+    "min_plus": (lambda m, e, d: m + e, "min"),
+    "plus_times": (lambda m, e, d: m * e, "add"),
+    "max_times": (lambda m, e, d: m * e, "max"),
+    "plus_dst": (lambda m, e, d: (e - m * d) * m, "add"),
 }
 
 
@@ -48,13 +49,12 @@ def test_kernel_matches_ref(shape, sem):
 def test_kernel_dtypes(dtype):
   rng = np.random.default_rng(0)
   cols, vals, mask = make_ell(rng, 32, 8, 40, dtype)
-  msg = jnp.asarray(rng.uniform(0, 2, (40, 1)).astype(dtype))
+  msg = jnp.asarray(rng.uniform(0, 2, (40,)).astype(dtype))
   act = jnp.ones((40,), bool)
-  dprop = jnp.zeros((32, 1), dtype)
-  proc = lambda m, e, d: m + e[..., None]
-  yk, _ = ell_spmv_pallas(cols, vals, mask, msg, act, dprop,
+  proc = lambda m, e, d: m + e
+  yk, _ = ell_spmv_pallas(cols, vals, mask, msg, act,
                           process=proc, reduce_kind="min")
-  yr, _ = ell_spmv_ref(cols, vals, mask, msg, act, dprop,
+  yr, _ = ell_spmv_ref(cols, vals, mask, msg, act,
                        process=proc, reduce_kind="min")
   np.testing.assert_allclose(np.asarray(yk, np.float32),
                              np.asarray(yr, np.float32), rtol=1e-2)
@@ -65,13 +65,12 @@ def test_kernel_block_shapes(br, bw):
   """Tiling must not change results (accumulation across slot tiles)."""
   rng = np.random.default_rng(4)
   cols, vals, mask = make_ell(rng, 48, 48, 64, np.float32)
-  msg = jnp.asarray(rng.standard_normal((64, 1)).astype(np.float32))
+  msg = jnp.asarray(rng.standard_normal((64,)).astype(np.float32))
   act = jnp.asarray(rng.uniform(size=64) > 0.4)
-  dprop = jnp.zeros((48, 1), np.float32)
-  proc = lambda m, e, d: m * e[..., None]
-  y0, _ = ell_spmv_pallas(cols, vals, mask, msg, act, dprop,
+  proc = lambda m, e, d: m * e
+  y0, _ = ell_spmv_pallas(cols, vals, mask, msg, act,
                           process=proc, reduce_kind="add")
-  yk, _ = ell_spmv_pallas(cols, vals, mask, msg, act, dprop,
+  yk, _ = ell_spmv_pallas(cols, vals, mask, msg, act,
                           process=proc, reduce_kind="add",
                           block_rows=br, block_slots=bw)
   np.testing.assert_allclose(np.asarray(yk), np.asarray(y0), rtol=1e-5)
@@ -80,14 +79,57 @@ def test_kernel_block_shapes(br, bw):
 def test_kernel_all_inactive():
   rng = np.random.default_rng(5)
   cols, vals, mask = make_ell(rng, 16, 8, 16, np.float32)
-  msg = jnp.ones((16, 1), jnp.float32)
+  msg = jnp.ones((16,), jnp.float32)
   act = jnp.zeros((16,), bool)
-  dprop = jnp.zeros((16, 1), np.float32)
-  yk, rk = ell_spmv_pallas(cols, vals, mask, msg, act, dprop,
-                           process=lambda m, e, d: m + e[..., None],
+  yk, rk = ell_spmv_pallas(cols, vals, mask, msg, act,
+                           process=lambda m, e, d: m + e,
                            reduce_kind="min")
   assert not np.any(np.asarray(rk))
   assert np.all(np.isinf(np.asarray(yk)))
+
+
+@pytest.mark.parametrize("bq", [1, 2, 8])
+def test_kernel_lane_blocks(bq):
+  """Lane blocking (one gather + kernel call per block) is invisible."""
+  rng = np.random.default_rng(6)
+  cols, vals, mask = make_ell(rng, 64, 16, 80, np.float32)
+  msg = jnp.asarray(rng.integers(0, 50, (80, 8)).astype(np.int32))
+  act = jnp.asarray(rng.uniform(size=80) > 0.3)
+  proc = lambda m, e, d: m + 1
+  yk, rk = ell_spmv_pallas(cols, vals, mask, msg, act, process=proc,
+                           reduce_kind="min", block_queries=bq)
+  yr, rr = ell_spmv_ref(cols, vals, mask, msg, act, process=proc,
+                        reduce_kind="min")
+  np.testing.assert_array_equal(np.asarray(rk), np.asarray(rr))
+  np.testing.assert_array_equal(np.asarray(yk), np.asarray(yr))
+
+
+@pytest.mark.parametrize("lanes", [None, 8], ids=["scalar", "q8"])
+def test_kernel_row_extents(rmat_small, monkeypatch, lanes):
+  """build_ell's slot_rows bound every slot's edges, and the kernel, which
+  then gathers only within them (in many small chunks here), matches the
+  full-width reference."""
+  from repro.core.graph import build_ell
+  from repro.kernels import ell_spmv
+  n, src, dst, w = rmat_small
+  g = build_ell(src, dst, w, n=n)
+  mask = np.asarray(g.mask)
+  assert list(g.slot_rows) == sorted(g.slot_rows, reverse=True)
+  assert min(g.slot_rows) < g.n_pad           # some padding is skipped
+  for s, r in enumerate(g.slot_rows):
+    assert not mask[s, r:].any()
+  monkeypatch.setattr(ell_spmv, "GATHER_BYTES", 4096)
+  rng = np.random.default_rng(7)
+  shape = (n,) if lanes is None else (n, lanes)
+  msg = jnp.asarray(rng.uniform(0, 5, shape).astype(np.float32))
+  act = jnp.asarray(rng.uniform(size=n) > 0.3)
+  proc = lambda m, e, d: m + e
+  yk, rk = ell_spmv_pallas(g.cols, g.vals, g.mask, msg, act, process=proc,
+                           reduce_kind="min", slot_rows=g.slot_rows)
+  yr, rr = ell_spmv_ref(g.cols, g.vals, g.mask, msg, act, process=proc,
+                        reduce_kind="min")
+  np.testing.assert_array_equal(np.asarray(rk), np.asarray(rr))
+  np.testing.assert_array_equal(np.asarray(yk), np.asarray(yr))
 
 
 # ---------------------------------------------------------------------------
