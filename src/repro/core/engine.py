@@ -37,25 +37,27 @@ def _superstep(graph, program: GraphProgram, state: EngineState,
                plan: Plan) -> EngineState:
   # SEND_MESSAGE for active vertices (vectorized; inactive lanes annihilated
   # inside the SpMV by the active mask).
-  msg = jax.vmap(program.send_message)(state.prop)
+  with jax.named_scope(spmv_lib.SCOPE_SEND):
+    msg = jax.vmap(program.send_message)(state.prop)
   # Generalized SpMV: PROCESS_MESSAGE ⊗ / REDUCE ⊕.
   y, recv = spmv_lib.spmv(graph, msg, state.active, state.prop, program,
                           backend=plan, with_recv=program.needs_recv)
   # APPLY for vertices that received a message.  Monotone programs
   # (needs_recv=False) apply unconditionally: APPLY(identity, old) == old,
   # so the receive mask and its E-sized scatter are skipped entirely.
-  new_prop = jax.vmap(program.apply)(y, state.prop)
-  if program.needs_recv:
-    new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
-    changed = jnp.logical_and(recv, program.activate(state.prop, new_prop))
-  else:
-    changed = program.activate(state.prop, new_prop)
-  return EngineState(
-      prop=new_prop,
-      active=changed,
-      iteration=state.iteration + 1,
-      num_active=jnp.sum(changed.astype(jnp.int32)),
-  )
+  with jax.named_scope(spmv_lib.SCOPE_APPLY):
+    new_prop = jax.vmap(program.apply)(y, state.prop)
+    if program.needs_recv:
+      new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
+      changed = jnp.logical_and(recv, program.activate(state.prop, new_prop))
+    else:
+      changed = program.activate(state.prop, new_prop)
+    return EngineState(
+        prop=new_prop,
+        active=changed,
+        iteration=state.iteration + 1,
+        num_active=jnp.sum(changed.astype(jnp.int32)),
+    )
 
 
 def run_graph_program(
@@ -145,35 +147,38 @@ def init_batched_state(init_prop: PyTree, init_active: Array
 def _batched_superstep(graph, program: GraphProgram,
                        state: BatchedEngineState,
                        plan: Plan) -> BatchedEngineState:
-  live = jnp.logical_not(state.done)
-  msg = jax.vmap(program.send_message)(state.prop)      # leaves [n, Q, ...]
-  # Fold the per-query frontier into the payload: inactive lanes (and whole
-  # retired columns) send the inert message.
-  lane_mask = jnp.logical_and(state.active, live[None, :])
-  msg = spmv_lib.mask_inert(msg, lane_mask, program)
-  vert_active = jnp.any(lane_mask, axis=1)              # bool[n] bitvector
+  with jax.named_scope(spmv_lib.SCOPE_SEND):
+    live = jnp.logical_not(state.done)
+    msg = jax.vmap(program.send_message)(state.prop)    # leaves [n, Q, ...]
+    # Fold the per-query frontier into the payload: inactive lanes (and
+    # whole retired columns) send the inert message.
+    lane_mask = jnp.logical_and(state.active, live[None, :])
+    msg = spmv_lib.mask_inert(msg, lane_mask, program)
+    vert_active = jnp.any(lane_mask, axis=1)            # bool[n] bitvector
   y, recv = spmv_lib.spmv(graph, msg, vert_active, state.prop, program,
                           backend=plan, with_recv=program.needs_recv)
-  new_prop = jax.vmap(program.apply)(y, state.prop)
-  if program.needs_recv:
-    # recv is per-vertex (any query delivered); per-lane correctness relies
-    # on the inert-message contract — untouched lanes see an identity-reduced
-    # input and APPLY must leave them unchanged (see GraphProgram docs).
-    new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
-    changed = jnp.logical_and(recv[:, None],
-                              program.activate(state.prop, new_prop))
-  else:
-    changed = program.activate(state.prop, new_prop)
-  changed = jnp.logical_and(changed, live[None, :])     # retired stay dead
-  num_active = jnp.sum(changed.astype(jnp.int32), axis=0)
-  return BatchedEngineState(
-      prop=new_prop,
-      active=changed,
-      iteration=state.iteration + 1,
-      done=jnp.logical_or(state.done, num_active == 0),
-      num_active=num_active,
-      iters=state.iters + live.astype(jnp.int32),
-  )
+  with jax.named_scope(spmv_lib.SCOPE_APPLY):
+    new_prop = jax.vmap(program.apply)(y, state.prop)
+    if program.needs_recv:
+      # recv is per-vertex (any query delivered); per-lane correctness
+      # relies on the inert-message contract — untouched lanes see an
+      # identity-reduced input and APPLY must leave them unchanged (see
+      # GraphProgram docs).
+      new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
+      changed = jnp.logical_and(recv[:, None],
+                                program.activate(state.prop, new_prop))
+    else:
+      changed = program.activate(state.prop, new_prop)
+    changed = jnp.logical_and(changed, live[None, :])   # retired stay dead
+    num_active = jnp.sum(changed.astype(jnp.int32), axis=0)
+    return BatchedEngineState(
+        prop=new_prop,
+        active=changed,
+        iteration=state.iteration + 1,
+        done=jnp.logical_or(state.done, num_active == 0),
+        num_active=num_active,
+        iters=state.iters + live.astype(jnp.int32),
+    )
 
 
 def run_batched(
@@ -251,8 +256,7 @@ def run_batched_rounds(graph, program: GraphProgram,
   scheduler drains the queue.
 
   Returns ``(state, trace)`` where ``trace[t] = int32`` total frontier
-  population at the *end* of step t (-1 for no-op steps) — the per-superstep
-  frontier-occupancy metric.
+  population at the *end* of step t (-1 for no-op steps).
   """
 
   plan = as_plan(backend)
@@ -261,10 +265,11 @@ def run_batched_rounds(graph, program: GraphProgram,
     s, trace = carry
     any_live = jnp.logical_not(jnp.all(s.done))
     s2 = _batched_superstep(graph, program, s, plan)
-    s = jax.tree_util.tree_map(
-        lambda a, b: jnp.where(any_live, a, b), s2, s)
-    trace = trace.at[t].set(
-        jnp.where(any_live, jnp.sum(s.num_active), jnp.int32(-1)))
+    with jax.named_scope(spmv_lib.SCOPE_APPLY):   # the step's state update
+      s = jax.tree_util.tree_map(
+          lambda a, b: jnp.where(any_live, a, b), s2, s)
+      trace = trace.at[t].set(
+          jnp.where(any_live, jnp.sum(s.num_active), jnp.int32(-1)))
     return s, trace
 
   trace0 = jnp.full((num_steps,), -1, jnp.int32)
@@ -287,8 +292,9 @@ def run_fixed_iters(graph, program: GraphProgram, init_prop: PyTree,
   def body(_, s):
     s = _superstep(graph, program, s, plan)
     if keep_all_active:
-      s = s._replace(active=jnp.ones_like(s.active),
-                     num_active=jnp.int32(s.active.shape[0]))
+      with jax.named_scope(spmv_lib.SCOPE_APPLY):
+        s = s._replace(active=jnp.ones_like(s.active),
+                       num_active=jnp.int32(s.active.shape[0]))
     return s
 
   return jax.lax.fori_loop(0, num_iters, body, state)

@@ -32,6 +32,20 @@ from repro.core.vertex_program import GraphProgram
 Array = jax.Array
 PyTree = Any
 
+# Named scopes of a superstep's phases, all under one root, ``graphmat/``.
+# They name the device operations of each phase in the compiled program's
+# ``op_name`` metadata, and so in a profile; they add no work.  Where phases
+# nest (the spill's COO pass), the outermost names the operation.
+SCOPE_SEND = "graphmat/send"
+SCOPE_GATHER = "graphmat/spmv/gather"
+SCOPE_KERNEL = "graphmat/spmv/kernel"
+SCOPE_UNPERMUTE = "graphmat/spmv/unpermute"
+SCOPE_SPILL = "graphmat/spmv/spill"
+SCOPE_SCATTER = "graphmat/spmv/scatter"
+SCOPE_APPLY = "graphmat/apply"
+SCOPE_INSTALL = "graphmat/install"
+SCOPE_EXTRACT = "graphmat/extract"
+
 _SCATTER_FAST = {"add", "min", "max", "any", "all"}
 _AXIS_RED = {"add": jnp.sum, "min": jnp.min, "max": jnp.max,
              "any": jnp.any, "all": jnp.all}
@@ -196,23 +210,25 @@ def _segment_reduce_scan(r: PyTree, dst: Array, n: int, red,
 def spmv_coo(g: graphlib.CooGraph, msg: PyTree, active: Array,
              dst_prop: PyTree, program: GraphProgram,
              with_recv: bool = True) -> Tuple[PyTree, Optional[Array]]:
-  m = _tree_gather(msg, g.src)                       # [E, ...]
-  if program.process_reads_dst:
-    dp = _tree_gather(dst_prop, g.dst)               # [E, ...]
-  else:
-    dp = _tree_gather(dst_prop, jnp.zeros_like(g.dst))
-  r = _vmap_process(program, 1)(m, g.w, dp)          # [E, ...]
-  valid = g.emask & active[g.src]
-  ident = program.identity_like(r)
-  r = _tree_where(valid, r, ident)
-  if program.reduce_kind in _SCATTER_FAST:
-    y = _segment_reduce_fast(r, g.dst, g.n, program.reduce_kind, ident)
-  else:
-    y = _segment_reduce_scan(r, g.dst, g.n, program.reduce_fn(), ident)
-  if not with_recv:
-    return y, None
-  recv = jnp.zeros((g.n,), jnp.bool_).at[g.dst].max(
-      valid, mode="drop", indices_are_sorted=True)
+  with jax.named_scope(SCOPE_GATHER):
+    m = _tree_gather(msg, g.src)                     # [E, ...]
+    if program.process_reads_dst:
+      dp = _tree_gather(dst_prop, g.dst)             # [E, ...]
+    else:
+      dp = _tree_gather(dst_prop, jnp.zeros_like(g.dst))
+    r = _vmap_process(program, 1)(m, g.w, dp)        # [E, ...]
+    valid = g.emask & active[g.src]
+    ident = program.identity_like(r)
+    r = _tree_where(valid, r, ident)
+  with jax.named_scope(SCOPE_SCATTER):
+    if program.reduce_kind in _SCATTER_FAST:
+      y = _segment_reduce_fast(r, g.dst, g.n, program.reduce_kind, ident)
+    else:
+      y = _segment_reduce_scan(r, g.dst, g.n, program.reduce_fn(), ident)
+    if not with_recv:
+      return y, None
+    recv = jnp.zeros((g.n,), jnp.bool_).at[g.dst].max(
+        valid, mode="drop", indices_are_sorted=True)
   return y, recv
 
 
@@ -223,28 +239,32 @@ def spmv_coo(g: graphlib.CooGraph, msg: PyTree, active: Array,
 
 def _ell_packed_compute(g: graphlib.EllGraph, msg: PyTree, active: Array,
                         dst_prop: PyTree, program: GraphProgram):
-  """Per-packed-row (y_packed, recv_packed) on the slot-major ELL block."""
-  m = _tree_gather(msg, g.cols)                      # [W, n_pad, ...]
-  valid = g.mask & active[g.cols]
-  if program.process_reads_dst:
-    safe_rows = jnp.minimum(g.row_of, g.n - 1)
-    dp = _tree_gather(dst_prop, safe_rows)           # [n_pad, ...]
-    dp = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (g.width,) + x.shape), dp)
-  else:
-    # process_message ignores dst_prop — feed a broadcast dummy row.
-    dp = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(
-            x[:1], (g.width, g.n_pad) + x.shape[1:]), dst_prop)
-  r = _vmap_process(program, 2)(m, g.vals, dp)       # [W, n_pad, ...]
-  ident = program.identity_like(r)
-  r = _tree_where(valid, r, ident)
-  if program.reduce_kind in _SCATTER_FAST:
-    axis_red = _AXIS_RED[program.reduce_kind]
-    y_packed = jax.tree_util.tree_map(lambda x: axis_red(x, axis=0), r)
-  else:
-    y_packed = _axis_tree_reduce(r, program.reduce_fn(), ident, axis=0)
-  recv_packed = jnp.any(valid, axis=0)
+  """Per-packed-row (y_packed, recv_packed) on the slot-major ELL block: the
+  gather, then the reduce over slots that the Pallas kernel does on its
+  path (named as the kernel's phase)."""
+  with jax.named_scope(SCOPE_GATHER):
+    m = _tree_gather(msg, g.cols)                    # [W, n_pad, ...]
+    valid = g.mask & active[g.cols]
+    if program.process_reads_dst:
+      safe_rows = jnp.minimum(g.row_of, g.n - 1)
+      dp = _tree_gather(dst_prop, safe_rows)         # [n_pad, ...]
+      dp = jax.tree_util.tree_map(
+          lambda x: jnp.broadcast_to(x[None], (g.width,) + x.shape), dp)
+    else:
+      # process_message ignores dst_prop — feed a broadcast dummy row.
+      dp = jax.tree_util.tree_map(
+          lambda x: jnp.broadcast_to(
+              x[:1], (g.width, g.n_pad) + x.shape[1:]), dst_prop)
+    r = _vmap_process(program, 2)(m, g.vals, dp)     # [W, n_pad, ...]
+    ident = program.identity_like(r)
+    r = _tree_where(valid, r, ident)
+  with jax.named_scope(SCOPE_KERNEL):
+    if program.reduce_kind in _SCATTER_FAST:
+      axis_red = _AXIS_RED[program.reduce_kind]
+      y_packed = jax.tree_util.tree_map(lambda x: axis_red(x, axis=0), r)
+    else:
+      y_packed = _axis_tree_reduce(r, program.reduce_fn(), ident, axis=0)
+    recv_packed = jnp.any(valid, axis=0)
   return y_packed, recv_packed
 
 
@@ -252,8 +272,21 @@ def _unpermute(g: graphlib.EllGraph, y_packed: PyTree, recv_packed: Array
                ) -> Tuple[PyTree, Array]:
   """Packed rows back to vertex order: a gather through ``packed_of`` (every
   vertex owns a packed row)."""
-  y = _tree_gather(y_packed, g.packed_of)
-  return y, recv_packed[g.packed_of]
+  with jax.named_scope(SCOPE_UNPERMUTE):
+    return _tree_gather(y_packed, g.packed_of), recv_packed[g.packed_of]
+
+
+def fold_spill(g: graphlib.EllGraph, y: PyTree, recv: Array, msg: PyTree,
+               active: Array, dst_prop: PyTree, program: GraphProgram
+               ) -> Tuple[PyTree, Array]:
+  """Fold the ELL's hub spill (a COO pass) into ``(y, recv)``."""
+  if g.spill is None:
+    return y, recv
+  with jax.named_scope(SCOPE_SPILL):
+    y_s, recv_s = spmv_coo(g.spill, msg, active, dst_prop, program)
+    red = program.reduce_fn()
+    y = _tree_where(recv_s, _tree_where(recv, red(y, y_s), y_s), y)
+    return y, recv | recv_s
 
 
 def spmv_ell(g: graphlib.EllGraph, msg: PyTree, active: Array,
@@ -262,11 +295,7 @@ def spmv_ell(g: graphlib.EllGraph, msg: PyTree, active: Array,
   y_packed, recv_packed = _ell_packed_compute(
       g, msg, active, dst_prop, program)
   y, recv = _unpermute(g, y_packed, recv_packed)
-  if g.spill is not None:
-    y_s, recv_s = spmv_coo(g.spill, msg, active, dst_prop, program)
-    red = program.reduce_fn()
-    y = _tree_where(recv_s, _tree_where(recv, red(y, y_s), y_s), y)
-    recv = recv | recv_s
+  y, recv = fold_spill(g, y, recv, msg, active, dst_prop, program)
   return y, (recv if with_recv else None)
 
 

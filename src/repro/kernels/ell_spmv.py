@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.spmv import SCOPE_GATHER, SCOPE_KERNEL
+
 Array = jax.Array
 
 _AXIS_RED = {"add": jnp.sum, "min": jnp.min, "max": jnp.max}
@@ -110,15 +112,18 @@ def _call(mg, vals, valid, dp, *, process, reduce_kind, out_dtype, br,
     args.append(dp)
   kern = functools.partial(_kernel, process=process, reduce_kind=reduce_kind,
                            out_dtype=out_dtype)
-  return pl.pallas_call(
-      kern,
-      grid=(n_pad // br,),
-      in_specs=in_specs,
-      out_specs=pl.BlockSpec((bq, 1, br), lambda i: (0, 0, i)),
-      out_shape=jax.ShapeDtypeStruct((bq, 1, n_pad), out_dtype),
-      interpret=interpret,
-      name="ell_spmv",
-  )(*args)
+  # Scoped here, inside the branch ``platform_dependent`` picks: its
+  # branches do not inherit the caller's scope.
+  with jax.named_scope(SCOPE_KERNEL):
+    return pl.pallas_call(
+        kern,
+        grid=(n_pad // br,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bq, 1, br), lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((bq, 1, n_pad), out_dtype),
+        interpret=interpret,
+        name="ell_spmv",
+    )(*args)
 
 
 def ell_spmv_pallas(
@@ -190,29 +195,37 @@ def ell_spmv_pallas(
   else:
     kernel = functools.partial(_call, interpret=interpret)
 
+  # Named scopes: the gathers (of messages, the frontier and dst
+  # properties) apart from the kernel calls and their combine.
   nb = k // bq
-  m_t = lanes.T.reshape(nb, bq, n_src)
-  per_lane_d = dl is not None and dl.shape[1] > 1
-  if per_lane_d:
-    assert dl.shape[1] == k, f"dprop lanes {dl.shape[1]} != K={k}"
-    d_t = dl.T.reshape(nb, bq, 1, n_pad)
-  else:
-    d_shared = None if dl is None else dl.T[:, None, :]   # [1, 1, n_pad]
+  with jax.named_scope(SCOPE_GATHER):
+    m_t = lanes.T.reshape(nb, bq, n_src)
+    per_lane_d = dl is not None and dl.shape[1] > 1
+    if per_lane_d:
+      assert dl.shape[1] == k, f"dprop lanes {dl.shape[1]} != K={k}"
+      d_t = dl.T.reshape(nb, bq, 1, n_pad)
+    else:
+      d_shared = None if dl is None else dl.T[:, None, :]  # [1, 1, n_pad]
 
-  y = jnp.full((nb, bq, 1, n_pad), _identity_scalar(reduce_kind, out_dtype),
-               out_dtype)
-  recv = jnp.zeros((n_pad,), bool)
+  with jax.named_scope(SCOPE_KERNEL):
+    y = jnp.full((nb, bq, 1, n_pad),
+                 _identity_scalar(reduce_kind, out_dtype), out_dtype)
+    recv = jnp.zeros((n_pad,), bool)
   for s0, s1, r in chunks:
-    c, e, msk = (a[s0:s1, :r] for a in (cols, vals, mask))   # [CW, R]
-    valid = jnp.logical_and(msk, active[c])
+    with jax.named_scope(SCOPE_GATHER):
+      c, e, msk = (a[s0:s1, :r] for a in (cols, vals, mask))  # [CW, R]
+      valid = jnp.logical_and(msk, active[c])
+      v8 = valid.astype(jnp.int8)
     br = block_rows or _pick_block(
         r, max(unit, TILE_BYTES // (isz * bq * (s1 - s0))), unit)
     call = functools.partial(kernel, process=process, reduce_kind=reduce_kind,
                              out_dtype=out_dtype, br=br)
 
-    def block(mb, db, c=c, e=e, v8=valid.astype(jnp.int8), call=call, r=r):
-      return call(jnp.take(mb, c, axis=1, mode="clip"), e, v8,
-                  None if db is None else db[..., :r])
+    def block(mb, db, c=c, e=e, v8=v8, call=call, r=r):
+      with jax.named_scope(SCOPE_GATHER):
+        mg = jnp.take(mb, c, axis=1, mode="clip")
+        db = None if db is None else db[..., :r]
+      return call(mg, e, v8, db)
 
     if nb == 1:
       part = block(m_t[0], d_t[0] if per_lane_d else d_shared)[None]
@@ -220,7 +233,10 @@ def ell_spmv_pallas(
       part = jax.lax.map(lambda a: block(*a), (m_t, d_t))
     else:
       part = jax.lax.map(lambda mb: block(mb, d_shared), m_t)
-    y = y.at[..., :r].set(_COMBINE[reduce_kind](y[..., :r], part))
-    recv = recv.at[:r].set(jnp.logical_or(recv[:r], jnp.any(valid, axis=0)))
-  y = y.reshape(k, n_pad).T                               # [n_pad, K]
+    with jax.named_scope(SCOPE_KERNEL):
+      y = y.at[..., :r].set(_COMBINE[reduce_kind](y[..., :r], part))
+      recv = recv.at[:r].set(jnp.logical_or(recv[:r],
+                                            jnp.any(valid, axis=0)))
+  with jax.named_scope(SCOPE_KERNEL):
+    y = y.reshape(k, n_pad).T                             # [n_pad, K]
   return (y if msg.ndim == 2 else y[:, 0]), recv

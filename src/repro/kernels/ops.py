@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graph as graphlib
-from repro.core.spmv import _tree_where, _unpermute, spmv_coo
+from repro.core.spmv import SCOPE_GATHER, _unpermute, fold_spill
 from repro.core.vertex_program import GraphProgram
 from repro.kernels.ell_spmv import ell_spmv_pallas
 
@@ -36,7 +36,8 @@ def spmv_ell_pallas(g: graphlib.EllGraph, msg: PyTree, active: Array,
   if program.process_reads_dst:
     dp_leaves = jax.tree_util.tree_leaves(dst_prop)
     assert len(dp_leaves) == 1, "pallas path: single-leaf dst_prop only"
-    dpp = dp_leaves[0][jnp.minimum(g.row_of, g.n - 1)]
+    with jax.named_scope(SCOPE_GATHER):
+      dpp = dp_leaves[0][jnp.minimum(g.row_of, g.n - 1)]
 
   y_leaf, recv = ell_spmv_pallas(
       g.cols, g.vals, g.mask, m, active, dpp,
@@ -45,9 +46,4 @@ def spmv_ell_pallas(g: graphlib.EllGraph, msg: PyTree, active: Array,
   y_packed = jax.tree_util.tree_unflatten(msg_def, [y_leaf])
 
   y, recv = _unpermute(g, y_packed, recv)
-  if g.spill is not None:
-    y_s, recv_s = spmv_coo(g.spill, msg, active, dst_prop, program)
-    red = program.reduce_fn()
-    y = _tree_where(recv_s, _tree_where(recv, red(y, y_s), y_s), y)
-    recv = recv | recv_s
-  return y, recv
+  return fold_spill(g, y, recv, msg, active, dst_prop, program)

@@ -1,9 +1,17 @@
 """Lightweight service counters/histograms (host-side, no deps).
 
 The serving layer's observability surface: monotonically-increasing
-counters, gauges, and power-of-two-bucketed histograms.  Everything is plain
-Python on the host — metrics are recorded at continuous-batching round
-boundaries, never inside traced code.
+counters, gauges, and power-of-two-bucketed histograms, and the names of the
+host spans a served round records.  Everything is plain Python on the host —
+metrics are recorded at continuous-batching round boundaries, never inside
+traced code.
+
+Spans are ``jax.profiler.TraceAnnotation`` scopes of the server thread.
+They cost next to nothing unless a profile is being taken
+(``jax.profiler.trace``), which puts them on the device's clock, so an idle
+gap of the device lies under what the host was doing then.  The device
+programs' phases are named by ``jax.named_scope`` under ``graphmat/``
+(:mod:`repro.core.spmv`).
 """
 
 from __future__ import annotations
@@ -11,6 +19,20 @@ from __future__ import annotations
 import math
 import threading
 from typing import Dict, Optional
+
+
+# One per round that does work (:meth:`GraphQueryServer.step_round`), with
+# its three phases as children.
+SPAN_ROUND = "graphmat.round"
+SPAN_ADMIT = "graphmat.round.admit"          # one SPAN_INSTALL per admission
+SPAN_SUPERSTEPS = "graphmat.round.supersteps"  # the round program + its fetch
+SPAN_RETIRE = "graphmat.round.retire"        # one SPAN_EXTRACT per retirement
+# One query's column into / out of the batched state; argument ``qid``.
+SPAN_INSTALL = "graphmat.install"
+SPAN_EXTRACT = "graphmat.extract"
+# Every device-to-host fetch of the round: the superstep trace, ``done``,
+# ``iters`` and each extracted column.
+SPAN_SYNC = "graphmat.sync"
 
 
 class Histogram:

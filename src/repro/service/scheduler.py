@@ -14,8 +14,9 @@ Rounds of ``steps_per_round`` supersteps run under one jit; between rounds
 the scheduler retires converged columns mid-flight and swaps queued queries
 into the freed slots *without restarting* the unconverged neighbors — slot
 state persists across the host round-trip (continuous batching, not static
-batching).  Per-round and per-superstep metrics land in a
-:class:`~repro.service.metrics.Counters`.
+batching).  Per-round metrics land in a
+:class:`~repro.service.metrics.Counters`, and each round records host spans
+(:mod:`repro.service.metrics`) that a profile shows.
 
 Threading model
 ---------------
@@ -88,11 +89,14 @@ import numpy as np
 from repro.core.backends import Plan, PlanLike, Planner, as_plan
 from repro.core.engine import (BatchedEngineState, init_batched_state,
                                mask_columns, run_batched_rounds)
+from repro.core.spmv import SCOPE_EXTRACT, SCOPE_INSTALL
 from repro.core.vertex_program import GraphProgram
 from repro.service.admission import (AdmissionPolicy, AdmissionRequest,
                                      PolicyLike, make_policy)
 from repro.service.cache import ResultCache, graph_fingerprint
-from repro.service.metrics import Counters
+from repro.service.metrics import (SPAN_ADMIT, SPAN_EXTRACT, SPAN_INSTALL,
+                                   SPAN_RETIRE, SPAN_ROUND, SPAN_SUPERSTEPS,
+                                   SPAN_SYNC, Counters)
 
 Array = jax.Array
 PyTree = Any
@@ -334,9 +338,7 @@ class GraphQueryServer:
     self._num_settled_live = 0
 
     self._install_fn = jax.jit(self._install)
-    self._extract_fn = jax.jit(
-        lambda prop, slot: jax.tree_util.tree_map(
-            lambda x: x[:, slot], prop))
+    self._extract_fn = jax.jit(self._extract)
     self._mask_fn = jax.jit(mask_columns)
     self._reset_engine_locked(graph)
 
@@ -739,18 +741,25 @@ class GraphQueryServer:
   def _install(state: BatchedEngineState, prop_col: PyTree,
                active_col: Array, slot) -> BatchedEngineState:
     """Swap a fresh query into ``slot`` without disturbing neighbors."""
-    prop = jax.tree_util.tree_map(
-        lambda full, col: full.at[:, slot].set(col), state.prop, prop_col)
-    active = state.active.at[:, slot].set(active_col)
-    na = jnp.sum(active_col.astype(jnp.int32))
-    return BatchedEngineState(
-        prop=prop,
-        active=active,
-        iteration=state.iteration,
-        done=state.done.at[slot].set(na == 0),
-        num_active=state.num_active.at[slot].set(na),
-        iters=state.iters.at[slot].set(0),
-    )
+    with jax.named_scope(SCOPE_INSTALL):
+      prop = jax.tree_util.tree_map(
+          lambda full, col: full.at[:, slot].set(col), state.prop, prop_col)
+      active = state.active.at[:, slot].set(active_col)
+      na = jnp.sum(active_col.astype(jnp.int32))
+      return BatchedEngineState(
+          prop=prop,
+          active=active,
+          iteration=state.iteration,
+          done=state.done.at[slot].set(na == 0),
+          num_active=state.num_active.at[slot].set(na),
+          iters=state.iters.at[slot].set(0),
+      )
+
+  @staticmethod
+  def _extract(prop: PyTree, slot) -> PyTree:
+    """One slot's property column."""
+    with jax.named_scope(SCOPE_EXTRACT):
+      return jax.tree_util.tree_map(lambda x: x[:, slot], prop)
 
   def _admit_locked(self) -> int:
     admitted = 0
@@ -764,9 +773,10 @@ class GraphQueryServer:
       self.counters.observe("queue.wait_ms", wait_ms)
       self.counters.observe_labeled("queue.wait_ms", wait_ms,
                                     tenant=req.tenant)
-      prop_col, active_col = self.family.init_column(req.spec)
-      self._state = self._install_fn(self._state, prop_col, active_col,
-                                     jnp.int32(slot))
+      with jax.profiler.TraceAnnotation(SPAN_INSTALL, qid=req.seq):
+        prop_col, active_col = self.family.init_column(req.spec)
+        self._state = self._install_fn(self._state, prop_col, active_col,
+                                       jnp.int32(slot))
       self._slot_key[slot] = req.key
       admitted += 1
     if admitted:
@@ -775,8 +785,10 @@ class GraphQueryServer:
     return admitted
 
   def _retire_locked(self) -> int:
-    done = np.asarray(self._state.done)
-    iters = np.asarray(self._state.iters)
+    with jax.profiler.TraceAnnotation(SPAN_SYNC):
+      done = np.asarray(self._state.done)
+    with jax.profiler.TraceAnnotation(SPAN_SYNC):
+      iters = np.asarray(self._state.iters)
     retired = 0
     for slot in range(self.num_slots):
       key = self._slot_key[slot]
@@ -785,9 +797,12 @@ class GraphQueryServer:
       forced = iters[slot] >= self.max_steps_per_query
       if not (done[slot] or forced):
         continue
-      col = self._extract_fn(self._state.prop, jnp.int32(slot))
-      result = self.family.extract(col)
       waiters = self._waiters.pop(key, [])
+      with jax.profiler.TraceAnnotation(
+          SPAN_EXTRACT, qid=waiters[0] if waiters else -1):
+        col = self._extract_fn(self._state.prop, jnp.int32(slot))
+        with jax.profiler.TraceAnnotation(SPAN_SYNC):
+          result = self.family.extract(col)
       for qid in waiters:
         ticket = self._tickets[qid]
         if ticket.event.is_set():
@@ -823,29 +838,34 @@ class GraphQueryServer:
     with self._engine_lock:
       with self._cond:
         self._expire_locked(self._clock() if now is None else now)
+        if not (self._policy.depth()
+                or any(k is not None for k in self._slot_key)):
+          return False
+      with jax.profiler.TraceAnnotation(SPAN_ROUND):
+        return self._round_locked()
+
+  def _round_locked(self) -> bool:
+    """Admit, run the round's supersteps, retire (under the engine lock)."""
+    with self._cond:
+      with jax.profiler.TraceAnnotation(SPAN_ADMIT):
         self._admit_locked()
-        in_flight = sum(1 for q in self._slot_key if q is not None)
-      if in_flight == 0:
-        return False
-      # The heavy SpMM rounds run outside the bookkeeping lock: submissions
-      # land in the queue while the device crunches.
+      in_flight = sum(1 for q in self._slot_key if q is not None)
+    if in_flight == 0:
+      return False
+    # The heavy SpMM rounds run outside the bookkeeping lock: submissions
+    # land in the queue while the device crunches.
+    with jax.profiler.TraceAnnotation(SPAN_SUPERSTEPS):
       self._state, trace = self._round_fn(self.graph, self._state)
-      self.counters.inc("rounds")
-      trace = np.asarray(trace)
-      real = trace[trace >= 0]
-      self.counters.inc("supersteps", float(real.size))
-      n = jax.tree_util.tree_leaves(self._state.prop)[0].shape[0]
-      for total_active in real:
-        # Frontier occupancy: fraction of the [n, Q] frontier matrix set.
-        self.counters.observe("superstep.frontier_fill",
-                              float(total_active) / float(n * self.num_slots))
-        self.counters.observe("superstep.frontier_active",
-                              float(total_active))
-      self.counters.observe("round.slot_utilization",
-                            in_flight / self.num_slots)
-      with self._cond:
+      with jax.profiler.TraceAnnotation(SPAN_SYNC):
+        trace = np.asarray(trace)
+    self.counters.inc("rounds")
+    self.counters.inc("supersteps", float(np.count_nonzero(trace >= 0)))
+    self.counters.observe("round.slot_utilization",
+                          in_flight / self.num_slots)
+    with self._cond:
+      with jax.profiler.TraceAnnotation(SPAN_RETIRE):
         self._retire_locked()
-      return True
+    return True
 
   def drain(self, max_rounds: int = 100_000) -> Dict[int, Any]:
     """Run rounds until queue and slots are empty; returns all successful
