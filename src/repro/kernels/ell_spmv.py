@@ -19,8 +19,11 @@ functions, and load-balanced partitions.  The TPU translation:
   temporary stays within :data:`GATHER_BYTES` at any graph size.
 * **Row extents** — rows are sorted by in-degree, so slot s holds edges only
   in the first ``slot_rows[s]`` packed rows.  A chunk gathers and reduces
-  only those R rows, and chunks widen as R shrinks: on a power-law graph
-  this skips most of the ELL's padding.
+  the first R rows of each of its slots, R its first slot's extent, so a
+  slot whose extent is shorter is fetched with padding.  :func:`chunk_plan`
+  ends a chunk where the extents fall below :data:`EXTENT_CUT` of its R,
+  which keeps that padding small on a power-law graph; on a regular graph
+  chunks are as wide as the budgets allow.
 * **Tiling** — each call's grid runs over row tiles of BR packed rows; a
   tile reduces its CW slots in VMEM into ``y[BQ, 1, BR]``.
 * **Inlining** — the user's PROCESS_MESSAGE/REDUCE are traced straight into
@@ -33,11 +36,12 @@ functions, and load-balanced partitions.  The TPU translation:
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.spmv import SCOPE_GATHER, SCOPE_KERNEL
 
@@ -51,6 +55,9 @@ _COMBINE = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 GATHER_BYTES = 1 << 28
 # Target bytes of one message tile [BQ, CW, BR] in VMEM.
 TILE_BYTES = 1 << 20
+# A slot chunk ends before a slot whose row extent is below this share of
+# the chunk's first extent (the gathers fetch every slot to that extent).
+EXTENT_CUT = 7 / 8
 
 
 def _identity_scalar(kind: str, dtype):
@@ -110,6 +117,11 @@ def _call(mg, vals, valid, dp, *, process, reduce_kind, out_dtype, br,
   if dp is not None:
     in_specs.append(pl.BlockSpec((dp.shape[0], 1, br), lambda i: (0, 0, i)))
     args.append(dp)
+  if not interpret:
+    # Operands in HBM, read by the kernel's own pipeline: XLA would stage a
+    # small chunk into VMEM with copies outside the kernel, whose time in a
+    # profile would then leave out the kernel's memory traffic.
+    args = [pltpu.with_memory_space_constraint(a, pltpu.HBM) for a in args]
   kern = functools.partial(_kernel, process=process, reduce_kind=reduce_kind,
                            out_dtype=out_dtype)
   # Scoped here, inside the branch ``platform_dependent`` picks: its
@@ -124,6 +136,37 @@ def _call(mg, vals, valid, dp, *, process, reduce_kind, out_dtype, br,
         interpret=interpret,
         name="ell_spmv",
     )(*args)
+
+
+def chunk_plan(slot_rows: Sequence[int], n_pad: int, lanes_per_block: int,
+               itemsize: int, unit: int, block_slots: Optional[int] = None
+               ) -> List[Tuple[int, int, int]]:
+  """Slot chunks ``(s0, s1, r)``: slots ``[s0, s1)`` gathered and reduced
+  over their first ``r`` packed rows.
+
+  The chunks cover the slots of nonzero extent in order; ``r`` is the first
+  slot's extent rounded up to ``unit`` rows.  A chunk ends at the first of:
+  its width cap (``block_slots``, else as many slots as :data:`GATHER_BYTES`
+  allows at ``r`` and :data:`TILE_BYTES` at one row unit, in multiples of
+  8 above 8), and a slot whose extent is below :data:`EXTENT_CUT` of the
+  first's.  Equal extents give chunks as wide as the cap.
+  """
+  rows = list(slot_rows)
+  w = next((s for s, x in enumerate(rows) if x <= 0), len(rows))
+  budget = GATHER_BYTES // itemsize         # elements of one gathered chunk
+  chunks, s0 = [], 0
+  while s0 < w:
+    r = min(n_pad, -(-rows[s0] // unit) * unit)
+    cap = block_slots or max(1, min(
+        budget // (lanes_per_block * r),
+        TILE_BYTES // (itemsize * lanes_per_block * unit)))
+    cap = cap - cap % 8 if cap > 8 else cap  # sublane multiples compile faster
+    end = min(w, s0 + cap)
+    s1 = next((s for s in range(s0 + 1, end)
+               if rows[s] < EXTENT_CUT * rows[s0]), end)
+    chunks.append((s0, s1, r))
+    s0 = s1
+  return chunks
 
 
 def ell_spmv_pallas(
@@ -149,8 +192,8 @@ def ell_spmv_pallas(
     reduce_kind: add | min | max.
     block_rows: rows per kernel tile (BR; a multiple of 128 on TPU, or
       n_pad).
-    block_slots: slots per gather + kernel call (CW; the last chunk may be
-      narrower).
+    block_slots: most slots per gather + kernel call (CW; chunks end
+      earlier where the row extents fall, :func:`chunk_plan`).
     block_queries: lanes per gather + kernel call (BQ divides K).
     slot_rows: non-increasing static row extents: slot s holds edges only
       in packed rows ``[0, slot_rows[s])`` (None: in all rows).
@@ -176,16 +219,7 @@ def ell_spmv_pallas(
   bq = block_queries or _lane_block(k, max(1, budget // max(rows[0], 1)))
   assert k % bq == 0, f"block_queries {bq} must divide K={k}"
 
-  # Greedy slot chunks: each as wide as the budget allows at its row extent
-  # (rounded up to the row unit); slots past the last nonempty one are skipped.
-  chunks, s0 = [], 0
-  while s0 < w and rows[s0] > 0:
-    r = min(n_pad, -(-rows[s0] // unit) * unit)
-    cw = block_slots or max(1, min(budget // (bq * r),
-                                   TILE_BYTES // (isz * bq * unit)))
-    cw = cw - cw % 8 if cw > 8 else cw      # sublane multiples compile faster
-    chunks.append((s0, min(w, s0 + cw), r))
-    s0 += cw
+  chunks = chunk_plan(rows, n_pad, bq, isz, unit, block_slots)
 
   if interpret is None:
     def kernel(*a, **kw):

@@ -104,6 +104,10 @@ def test_ell_kernel_compiles_at_scale_22(spec, lanes):
       spec((ELL_WIDTH, N), jnp.bool_), spec(msg_shape, jnp.int32),
       spec((N,), jnp.bool_)).compile()
   assert KERNEL_MARK in compiled.as_text()
+  # Its operands are pinned to HBM, so the kernel's own time covers reading
+  # them (XLA would otherwise stage small chunks into VMEM outside it).  An
+  # unpinned call lists no colors: ``"input_memory_space_colors":[]``.
+  assert '"input_memory_space_colors":[{' in compiled.as_text()
   # The message gather runs in bounded chunks: no [W, n_pad] temporary.
   assert _fits(compiled).temp_size_in_bytes < 2 * 2**30
 
