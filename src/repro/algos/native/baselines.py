@@ -108,3 +108,28 @@ def native_cf(users: Array, items_g: Array, ratings: Array, n: int, k: int,
     return p
 
   return jax.lax.fori_loop(0, num_iters, body, p0)
+
+
+def native_cdlp(src, dst, n: int, num_iters: int) -> np.ndarray:
+  """Graphalytics CDLP in plain numpy on the host: labels start as the
+  vertex ids; each iteration sorts the packed keys ``dst * n + label[src]``
+  (int64), so that each destination's equal labels form runs, and gives a
+  destination the label of its longest run, ties to the smallest; a vertex
+  with no in-arc keeps its label.  Returns int32 labels [n]."""
+  src = np.asarray(src, np.int64)
+  dst = np.asarray(dst, np.int64)
+  labels = np.arange(n, dtype=np.int64)
+  if not src.size:
+    return labels.astype(np.int32)
+  for _ in range(num_iters):
+    key = np.sort(dst * n + labels[src])
+    starts = np.flatnonzero(np.r_[True, key[1:] != key[:-1]])
+    counts = np.diff(np.r_[starts, key.size])
+    run_dst, run_lab = key[starts] // n, key[starts] % n
+    heads = np.flatnonzero(np.r_[True, run_dst[1:] != run_dst[:-1]])
+    best = np.repeat(np.maximum.reduceat(counts, heads),
+                     np.diff(np.r_[heads, counts.size]))
+    # Runs of a destination come in increasing label order.
+    won = np.minimum.reduceat(np.where(counts == best, run_lab, n), heads)
+    labels[run_dst[heads]] = won
+  return labels.astype(np.int32)

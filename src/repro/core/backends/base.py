@@ -12,6 +12,8 @@ behavior: the graph *container* dominates.  An explicit plan naming a backend
 that cannot execute the call (e.g. ``Plan(backend="ell")`` on a
 :class:`~repro.core.graph.CooGraph`) falls back to structural auto-selection,
 exactly as ``backend="ell"`` used to fall through the old isinstance chain.
+A ``mode`` program never falls back: its reduce exists on one backend only,
+so an explicit plan that cannot run it is an error.
 """
 
 from __future__ import annotations
@@ -104,12 +106,17 @@ def resolve(plan: Plan, graph, msg: PyTree, dst_prop: PyTree,
   An explicitly named backend wins iff it supports the call; otherwise the
   container dominates (legacy string semantics) and selection falls through
   to structural auto: highest-priority backend whose :meth:`Backend.eligible`
-  accepts the (graph, payload, program) triple.
+  accepts the (graph, payload, program) triple.  An explicit plan that
+  cannot run a ``mode`` program raises instead.
   """
   if not plan.is_auto:
     impl = get_backend(plan.backend)
     if impl.supports(graph, msg, dst_prop, program):
       return impl
+    if program.reduce_kind == "mode":
+      raise TypeError(
+          f"backend {plan.backend!r} cannot run the mode reduce of program "
+          f"{program.name!r} on {type(graph).__name__}")
   for name in registered_backends():
     impl = _REGISTRY[name]
     if impl.eligible(graph, msg, dst_prop, program):

@@ -13,7 +13,8 @@ class DenseBackend(base.Backend):
   priority = 100  # a DenseGraph container always routes here
 
   def supports(self, graph, msg, dst_prop, program):
-    return isinstance(graph, graphlib.DenseGraph)
+    return (isinstance(graph, graphlib.DenseGraph)
+            and program.reduce_kind != "mode")
 
   def execute(self, graph, msg, active, dst_prop, program, plan, with_recv):
     y, recv = spmv_lib.spmv_dense(graph.vals, graph.struct, msg, active,

@@ -13,7 +13,8 @@ class EllBackend(base.Backend):
   priority = 80  # EllGraph fallback when the Pallas kernel is ineligible
 
   def supports(self, graph, msg, dst_prop, program):
-    return isinstance(graph, graphlib.EllGraph)
+    return (isinstance(graph, graphlib.EllGraph)
+            and program.reduce_kind != "mode")
 
   def execute(self, graph, msg, active, dst_prop, program, plan, with_recv):
     return spmv_lib.spmv_ell(graph, msg, active, dst_prop, program,

@@ -20,8 +20,10 @@ class PallasEllBackend(base.Backend):
   def supports(self, graph, msg, dst_prop, program):
     # Container-level only: an *explicit* pallas plan on an EllGraph always
     # routes here (shape restrictions are asserted in kernels.ops, matching
-    # the legacy backend="pallas" error behavior).
-    return isinstance(graph, graphlib.EllGraph)
+    # the legacy backend="pallas" error behavior), but for a mode program,
+    # which no ELL path runs.
+    return (isinstance(graph, graphlib.EllGraph)
+            and program.reduce_kind != "mode")
 
   def eligible(self, graph, msg, dst_prop, program):
     return (isinstance(graph, graphlib.EllGraph)

@@ -127,6 +127,13 @@ def partition_2d(src, dst, w=None, *, n: int, R: int, C: int,
                    w=put(bw), emask=put(bmask))
 
 
+def _refuse_mode(program: GraphProgram) -> None:
+  """The column blocks' partial results can only be combined by a monoid."""
+  if program.reduce_kind == "mode":
+    raise ValueError(f"program {program.name!r}: the 2-D runners cannot "
+                     "combine a mode reduce across column blocks")
+
+
 def _semiring_axis_reduce(y: PyTree, recv: Array, axis_name: str,
                           program: GraphProgram) -> Tuple[PyTree, Array]:
   kind = program.reduce_kind
@@ -222,6 +229,7 @@ def run_graph_program_2d(
   """
   from repro.core.engine import EngineState  # circular-import dodge
 
+  _refuse_mode(program)
   row = tuple(row_axes)
   rows_spec = row if len(row) > 1 else row[0]
   plan = as_plan(backend)
@@ -278,6 +286,7 @@ def run_graph_program_2d_batched(
   """
   from repro.core.engine import BatchedEngineState, init_batched_state
 
+  _refuse_mode(program)
   row = tuple(row_axes)
   rows_spec = row if len(row) > 1 else row[0]
   plan = as_plan(backend)

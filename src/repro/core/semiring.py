@@ -8,7 +8,10 @@ standard instances used by the paper's five algorithms.
 
 The ``reduce`` operation must be associative and commutative (the paper makes
 the same requirement) — this is what lets the backend parallelize the
-reduction over edge blocks, vector lanes and mesh devices.
+reduction over edge blocks, vector lanes and mesh devices.  The one
+exception is ``mode`` (the most frequent message), which is no monoid: it
+has no binary combine and no identity, and only the COO backend runs it,
+by a segmented sort of the arriving messages.
 """
 
 from __future__ import annotations
@@ -23,7 +26,8 @@ Array = jax.Array
 
 # Reduction kinds with hardware fast-paths.  ``generic`` falls back to a
 # segmented associative scan (still parallel, but no scatter fast-path).
-REDUCE_KINDS = ("add", "min", "max", "any", "all", "generic")
+# ``mode`` is not a monoid (see the module docstring).
+REDUCE_KINDS = ("add", "min", "max", "any", "all", "generic", "mode")
 
 
 def _identity_for(kind: str, dtype) -> Any:

@@ -13,7 +13,8 @@ Backends:
   * ``spmv_dense`` — O(n²) masked oracle for tests.
   * ``spmv_coo``   — gather + segmented reduce over a dst-sorted edge list
                      (scatter fast-paths for add/min/max/any; associative
-                     segmented scan for generic monoids).
+                     segmented scan for generic monoids; a segmented sort
+                     for ``mode``, the one reduce that is no monoid).
   * ``spmv_ell``   — degree-sorted slot-major ELL: gather + reduce over
                      slots — the layout consumed by the Pallas kernel; hub
                      spill edges are folded in via the COO path.
@@ -42,6 +43,7 @@ SCOPE_KERNEL = "graphmat/spmv/kernel"
 SCOPE_UNPERMUTE = "graphmat/spmv/unpermute"
 SCOPE_SPILL = "graphmat/spmv/spill"
 SCOPE_SCATTER = "graphmat/spmv/scatter"
+SCOPE_MODE = "graphmat/spmv/mode"
 SCOPE_APPLY = "graphmat/apply"
 SCOPE_INSTALL = "graphmat/install"
 SCOPE_EXTRACT = "graphmat/extract"
@@ -207,9 +209,38 @@ def _segment_reduce_scan(r: PyTree, dst: Array, n: int, red,
   return jax.tree_util.tree_map(scatter, v_scanned, ident)
 
 
+def segment_mode(lab: Array, dst: Array, valid: Array, n: int
+                 ) -> Tuple[Array, Array]:
+  """Per destination, the most frequent label of its valid arcs, ties to the
+  smallest, and whether it has a valid arc at all.
+
+  Sorts the (dst, label) pairs, so that equal labels of one destination form
+  runs; invalid arcs get the sentinel destination ``n``, sort last and are
+  dropped.  A label's count is read at the last arc of its run; the winner
+  is the smallest label whose run is as long as the destination's longest.
+  A destination without a valid arc gets the label dtype's largest value.
+  """
+  e = dst.shape[0]
+  d, lab = jax.lax.sort((jnp.where(valid, dst, n), lab), num_keys=2,
+                        is_stable=False)
+  pos = jnp.arange(e, dtype=jnp.int32)
+  first = jnp.concatenate([jnp.ones((1,), bool),
+                           (d[1:] != d[:-1]) | (lab[1:] != lab[:-1])])
+  count = pos - jax.lax.cummax(jnp.where(first, pos, 0)) + 1  # so far in run
+  kw = dict(mode="drop", indices_are_sorted=True)
+  best = jnp.zeros((n,), jnp.int32).at[d].max(count, **kw)
+  # A sentinel arc reads a clamped ``best``; its scatter below is dropped.
+  top = count == best[d]
+  none = jnp.iinfo(lab.dtype).max
+  y = jnp.full((n,), none, lab.dtype).at[d].min(jnp.where(top, lab, none),
+                                                **kw)
+  return y, best > 0
+
+
 def spmv_coo(g: graphlib.CooGraph, msg: PyTree, active: Array,
              dst_prop: PyTree, program: GraphProgram,
              with_recv: bool = True) -> Tuple[PyTree, Optional[Array]]:
+  mode = program.reduce_kind == "mode"
   with jax.named_scope(SCOPE_GATHER):
     m = _tree_gather(msg, g.src)                     # [E, ...]
     if program.process_reads_dst:
@@ -218,8 +249,19 @@ def spmv_coo(g: graphlib.CooGraph, msg: PyTree, active: Array,
       dp = _tree_gather(dst_prop, jnp.zeros_like(g.dst))
     r = _vmap_process(program, 1)(m, g.w, dp)        # [E, ...]
     valid = g.emask & active[g.src]
-    ident = program.identity_like(r)
-    r = _tree_where(valid, r, ident)
+    if not mode:
+      ident = program.identity_like(r)
+      r = _tree_where(valid, r, ident)
+  if mode:
+    leaves, treedef = jax.tree_util.tree_flatten(r)
+    if (len(leaves) != 1 or leaves[0].ndim != 1
+        or not jnp.issubdtype(leaves[0].dtype, jnp.integer)):
+      raise TypeError(f"program {program.name!r}: the mode reduce takes one "
+                      "integer scalar message per arc")
+    with jax.named_scope(SCOPE_MODE):
+      y, recv = segment_mode(leaves[0], g.dst, valid, g.n)
+    return (jax.tree_util.tree_unflatten(treedef, [y]),
+            recv if with_recv else None)
   with jax.named_scope(SCOPE_SCATTER):
     if program.reduce_kind in _SCATTER_FAST:
       y = _segment_reduce_fast(r, g.dst, g.n, program.reduce_kind, ident)

@@ -7,7 +7,8 @@ Mirrors the paper's user-facing surface (Section 4.1 and the SSSP appendix):
   edge.  Reading the destination vertex property is GraphMat's key
   expressivity extension over CombBLAS/PEGASUS (enables TC and CF).
 * ``reduce(a, b) -> a⊕b`` — associative + commutative combine of processed
-  messages arriving at one vertex.
+  messages arriving at one vertex (or ``mode``: the most frequent one, which
+  is no such combine).
 * ``apply(reduced, old_property) -> new_property`` — run for each vertex that
   received at least one message.
 * a vertex whose property *changed* under ``apply`` becomes active for the
@@ -79,7 +80,10 @@ class GraphProgram:
     process_message: ``(message, edge_value, dst_property) -> result``.
     reduce_kind: one of :data:`repro.core.semiring.REDUCE_KINDS`.  Fast
       scatter paths exist for add/min/max/any/all; ``generic`` uses a
-      segmented associative scan and requires ``reduce``.
+      segmented associative scan and requires ``reduce``.  ``mode`` is not
+      associative: the result is the value that arrives most often, ties
+      to the smallest.  It takes integer scalar messages only, has no
+      ``reduce`` and no identity, and runs on the COO backend alone.
     reduce: explicit combine fn (required for ``generic``; derived otherwise).
     reduce_identity: pytree of scalar identities matching the *result*
       structure (required for ``generic``; derived otherwise).
@@ -130,10 +134,17 @@ class GraphProgram:
           f"reduce_kind={self.reduce_kind!r} not in {sr.REDUCE_KINDS}")
     if self.reduce_kind == "generic" and self.reduce is None:
       raise ValueError("generic reduce_kind requires an explicit `reduce`")
+    if self.reduce_kind == "mode" and (
+        self.reduce is not None or self.reduce_identity is not None
+        or self.num_message_dims != 0):
+      raise ValueError("mode reduce_kind takes scalar messages and no "
+                       "`reduce` or `reduce_identity`")
 
   # -- derived helpers -------------------------------------------------------
 
   def reduce_fn(self) -> Callable[[PyTree, PyTree], PyTree]:
+    if self.reduce_kind == "mode":
+      raise ValueError(f"program {self.name!r}: mode has no binary reduce")
     if self.reduce is not None:
       return self.reduce
     leaf = sr.reduce_fn_for(self.reduce_kind)

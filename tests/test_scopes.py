@@ -126,6 +126,15 @@ def test_sssp_loop_is_scoped(graphs, backend, phases):
   _check_scoped(text, phases - {"send"})
 
 
+def test_cdlp_loop_is_scoped(graphs):
+  """CDLP's mode reduce (sort, runs, arg-max) runs under its own scope."""
+  from repro.algos.cdlp import _cdlp_jit
+  text = _cdlp_jit.lower(graphs[2]["coo"], num_iters=3,
+                         backend=Plan(backend="coo")).compile().as_text()
+  # CDLP sends its label as it is: SEND_MESSAGE compiles to nothing.
+  _check_scoped(text, {"spmv/gather", "spmv/mode", "apply"})
+
+
 @pytest.mark.parametrize("backend,phases", PATHS)
 def test_batched_rounds_loop_is_scoped(graphs, backend, phases):
   n, _, g = graphs
