@@ -98,14 +98,13 @@ def build_graphs(scale: int, seed: int) -> Graphs:
   ell = G.build_ell(src, dst, w, n=n)
   jax.block_until_ready((coo, ell))
   t2 = time.perf_counter()
-  spill = 0 if ell.spill is None else ell.spill.capacity
   report({"phase": "build", "n": n, "edges": int(src.size),
           "generate_s": t1 - t0, "build_s": t2 - t1,
           "device_bytes": sum(x.nbytes for x in
                               jax.tree_util.tree_leaves((coo, ell))),
           "ell_width": ell.width, "ell_n_pad": ell.n_pad,
           "ell_slots_in_extents": sum(ell.slot_rows),
-          "ell_spill_edges": spill})
+          "ell_split_rows": int(ell.split_rows.shape[0])})
   return Graphs(n, src, dst, w, coo, ell)
 
 

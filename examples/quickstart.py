@@ -23,7 +23,7 @@ def main():
   n = 1 << scale
   rng = np.random.default_rng(0)
   w = rng.uniform(0.1, 2.0, len(src)).astype(np.float32)
-  graph = build_ell(src, dst, w, n=n)   # degree-sorted ELL (+ hub spill)
+  graph = build_ell(src, dst, w, n=n)   # degree-sorted ELL (hubs split into rows)
 
   # --- the vertex program (paper appendix, SSSP) ------------------------
   sssp = GraphProgram(

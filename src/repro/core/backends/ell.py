@@ -1,4 +1,4 @@
-"""ELL backend: degree-sorted slot-major packed rows, reduce over slots (+ COO spill)."""
+"""ELL backend: degree-sorted slot-major packed rows, reduce over slots (+ split-row fold)."""
 
 from __future__ import annotations
 

@@ -62,7 +62,6 @@ class GraphStats:
   density: float        # nnz / n²
   ell_width: int = 0            # ELL slot width (EllGraph only)
   ell_efficiency: float = 0.0   # packed nnz / (n_pad · width)
-  spill_frac: float = 0.0       # fraction of edges in the COO spill
 
 
 def _degree_stats(in_deg: np.ndarray):
@@ -101,18 +100,17 @@ def compute_stats(graph) -> GraphStats:
                       nnz / max(graph.n * graph.n, 1))
   if isinstance(graph, graphlib.EllGraph):
     mask = np.asarray(graph.mask)
-    packed = int(mask.sum())
-    spill = 0
-    if graph.spill is not None:
-      spill = int(np.asarray(graph.spill.emask).sum())
-    nnz = packed + spill
-    in_deg = mask.sum(axis=0)[np.asarray(graph.row_of) < graph.n]
-    mean, mx, cv, hub = _degree_stats(in_deg.astype(np.float64))
+    nnz = int(mask.sum())
+    # A vertex's in-degree sums its home row and its split rows.
+    row_of = np.asarray(graph.row_of)
+    real = row_of < graph.n
+    in_deg = np.bincount(row_of[real], weights=mask.sum(axis=0)[real],
+                         minlength=graph.n)
+    mean, mx, cv, hub = _degree_stats(in_deg)
     return GraphStats(
         "ell", graph.n, nnz, nnz / max(graph.n, 1), mx, cv, hub,
         nnz / max(graph.n * graph.n, 1), ell_width=graph.width,
-        ell_efficiency=packed / max(mask.size, 1),
-        spill_frac=spill / max(nnz, 1))
+        ell_efficiency=nnz / max(mask.size, 1))
   raise TypeError(f"unknown graph container {type(graph)}")
 
 

@@ -10,9 +10,10 @@ wants *static shapes and unit-stride loads*.  We therefore provide:
   becomes tiling the edge array into equal-size tiles: perfectly balanced by
   construction.  Backend: gather + segmented reduce.
 * :class:`EllGraph` — degree-sorted ELLPACK rows (SELL-σ-style permutation)
-  with a fixed slot width and a COO spill for hub rows, stored slot-major
-  (``[width, n_pad]``) so packed rows lie along the TPU's lane axis.  This
-  is the VMEM-tileable format the Pallas kernel consumes.
+  with a fixed slot width, hub rows split into further rows of the same
+  block, stored slot-major (``[width, n_pad]``) so packed rows lie along the
+  TPU's lane axis.  This is the VMEM-tileable format the Pallas kernel
+  consumes.
 * ``dense_adjacency`` — small-graph oracle.
 
 All containers are registered pytrees of ``jax.Array``s with static metadata,
@@ -78,18 +79,22 @@ class CooGraph:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class EllGraph:
-  """Degree-sorted blocked-ELL + COO spill.
+  """Degree-sorted blocked-ELL with hub rows split across packed rows.
 
-  Rows (destination vertices) are permuted by in-degree so that padding waste
-  within a slot block is bounded; rows with in-degree > ``width`` spill their
-  excess edges into a COO tail that is processed by the segment backend.
+  A vertex of in-degree d owns ``ceil(d / width)`` packed rows (at least
+  one): its home row, ``packed_of[v]``, and one *split row* for each further
+  ``width`` of its incoming edges.  All rows are sorted by their own length,
+  so padding waste within a slot block is bounded; the split rows' results
+  are folded into their owners after the reduce over slots.
 
   ``cols[s, r]`` is the *source* vertex of the s-th incoming edge of packed
   row r (slot-major: rows are the minor, lane-dense axis); ``row_of[r]``
-  maps packed row -> vertex id; ``packed_of[v]`` is the inverse
-  permutation.  Rows are sorted by in-degree, so slot s holds edges only in
-  packed rows ``[0, slot_rows[s])``, a non-increasing extent the Pallas
-  kernel uses to skip padding (None: every row).
+  maps packed row -> owner vertex id; ``packed_of[v]`` is the home row of
+  vertex v.  ``split_rows`` lists the packed positions of the split rows,
+  ``split_owner`` their owners, non-decreasing.  Rows are sorted by length,
+  so slot s holds edges only in packed rows ``[0, slot_rows[s])``, a
+  non-increasing extent the Pallas kernel uses to skip padding (None: every
+  row).
   """
 
   n: int                 # static: number of vertices
@@ -97,14 +102,15 @@ class EllGraph:
   cols: Array            # int32[width, n_pad]  (source vertex ids)
   vals: Array            # [width, n_pad]       (edge values)
   mask: Array            # bool[width, n_pad]
-  row_of: Array          # int32[n_pad]  packed row -> vertex id
-  packed_of: Array       # int32[n]      vertex id -> packed row
-  spill: Optional[CooGraph]  # hub-row excess edges (or None)
+  row_of: Array          # int32[n_pad]  packed row -> owner vertex id
+  packed_of: Array       # int32[n]      vertex id -> home packed row
+  split_rows: Array      # int32[V]      packed rows beyond the home rows
+  split_owner: Array     # int32[V]      their owners, non-decreasing
   slot_rows: Optional[Tuple[int, ...]] = None  # static: row extent per slot
 
   def tree_flatten(self):
     children = (self.cols, self.vals, self.mask, self.row_of, self.packed_of,
-                self.spill)
+                self.split_rows, self.split_owner)
     return children, (self.n, self.width, self.slot_rows)
 
   @classmethod
@@ -190,73 +196,87 @@ def build_coo(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
   )
 
 
-def build_ell(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
-              width: Optional[int] = None, row_block: int = 128,
-              spill_frac_cap: float = 1.0) -> EllGraph:
-  """Build a degree-sorted :class:`EllGraph` (+ spill) from host edges.
+def ell_split(in_deg, width: Optional[int] = None
+              ) -> Tuple[int, np.ndarray]:
+  """The ELL's slot width and the number of packed rows of each vertex.
 
-  Args:
-    width: ELL slot width.  Default: the 95th-percentile in-degree rounded up
-      to a multiple of 8 — hub rows beyond it spill to COO (hybrid format).
-    row_block: pad packed rows to a multiple of this (the Pallas row tile
-      is a multiple of the 128-wide lane axis).
-    spill_frac_cap: sanity cap on the fraction of edges allowed to spill.
+  ``width`` defaults to the 95th-percentile nonzero in-degree rounded up to
+  a multiple of 8.  A vertex of in-degree d holds ``max(1, ceil(d / width))``
+  rows: its first ``width`` incoming edges in its home row, each further
+  ``width`` in a split row.
   """
-  dt = np.dtype(edge_dtype)
-  src, dst, w = _as_np_edges(src, dst, w, n, dt)
-  in_deg = np.bincount(dst, minlength=n).astype(np.int32)
+  in_deg = np.asarray(in_deg, np.int64)
   if width is None:
     nz = in_deg[in_deg > 0]
     q = int(np.percentile(nz, 95)) if nz.size else 1
     width = max(8, int(np.ceil(q / 8)) * 8)
+  return int(width), np.maximum(1, -(-in_deg // width))
 
-  # Degree-sorted row permutation (descending) — the SELL-σ idea with σ = n:
-  # dense rows cluster together, padding waste concentrates in few tiles.
-  perm = np.argsort(-in_deg, kind="stable").astype(np.int32)  # packed -> vid
-  inv = np.empty(n, np.int32)
-  inv[perm] = np.arange(n, dtype=np.int32)                    # vid -> packed
 
-  n_pad = int(np.ceil(n / row_block)) * row_block
+def build_ell(src, dst, w=None, *, n: int, edge_dtype=jnp.float32,
+              width: Optional[int] = None, row_block: int = 128) -> EllGraph:
+  """Build a degree-sorted :class:`EllGraph` from host edges.
+
+  Args:
+    width: ELL slot width (default: :func:`ell_split`'s).  A row of higher
+      in-degree continues in split rows of the same block.
+    row_block: pad packed rows to a multiple of this (the Pallas row tile
+      is a multiple of the 128-wide lane axis).
+  """
+  dt = np.dtype(edge_dtype)
+  src, dst, w = _as_np_edges(src, dst, w, n, dt)
+  in_deg = np.bincount(dst, minlength=n).astype(np.int64)
+  width, pieces = ell_split(in_deg, width)
+
+  # Rows 0..n-1 are the vertices' home rows, then the split rows in owner
+  # order; each holds up to `width` of its owner's edges.
+  extra = pieces - 1
+  first = np.cumsum(extra) - extra             # first split row, per vertex
+  owner = np.repeat(np.arange(n, dtype=np.int32), extra)
+  piece = np.arange(owner.size) - first[owner] + 1
+  length = np.concatenate([np.minimum(in_deg, width),
+                           np.minimum(width, in_deg[owner] - piece * width)])
+  owner_of = np.concatenate([np.arange(n, dtype=np.int32), owner])
+
+  # Length-sorted row permutation (descending) — the SELL-σ idea with σ = all
+  # rows: dense rows cluster together, padding waste concentrates in few
+  # tiles.  With no split rows this is the in-degree order.
+  perm = np.argsort(-length, kind="stable")                   # packed -> row
+  pos = np.empty(perm.size, np.int32)
+  pos[perm] = np.arange(perm.size, dtype=np.int32)            # row -> packed
+
+  n_pad = int(np.ceil(perm.size / row_block)) * row_block
   cols = np.full((width, n_pad), PAD, np.int32)
   vals = np.zeros((width, n_pad), dt)
   mask = np.zeros((width, n_pad), bool)
 
-  # Slot position of each edge within its destination row.
+  # Position of each edge within its destination's edges: its row and slot.
   order = np.argsort(dst, kind="stable")
   s_src, s_dst, s_w = src[order], dst[order], w[order]
-  if s_dst.size:
-    starts = np.searchsorted(s_dst, s_dst)  # first index of this dst run
-    slot = np.arange(s_dst.shape[0]) - starts
-  else:
-    slot = np.zeros(0, np.int64)
-  fits = slot < width
-  r = inv[s_dst[fits]]
-  cols[slot[fits], r] = s_src[fits]
-  vals[slot[fits], r] = s_w[fits]
-  mask[slot[fits], r] = True
+  k = np.arange(s_dst.shape[0]) - np.searchsorted(s_dst, s_dst)
+  p = k // width
+  row = np.where(p == 0, s_dst, n + first[s_dst] + p - 1)
+  slot, r = k % width, pos[row]
+  cols[slot, r] = s_src
+  vals[slot, r] = s_w
+  mask[slot, r] = True
 
-  spill_src, spill_dst, spill_w = s_src[~fits], s_dst[~fits], s_w[~fits]
-  total = max(src.shape[0], 1)
-  assert spill_src.shape[0] <= spill_frac_cap * total, (
-      f"{spill_src.shape[0]}/{total} edges spill; raise width")
-  spill = None
-  if spill_src.shape[0]:
-    spill = build_coo(spill_src, spill_dst, spill_w, n=n, edge_dtype=dt)
-  # Rows with in-degree > s, rounded up to whole row blocks.
-  deeper = n - np.cumsum(np.bincount(np.minimum(in_deg, width),
-                                     minlength=width + 1))[:width]
+  # Rows longer than s, rounded up to whole row blocks.
+  deeper = perm.size - np.cumsum(np.bincount(length,
+                                             minlength=width + 1))[:width]
   slot_rows = tuple(min(n_pad, -(-int(c) // row_block) * row_block)
                     for c in deeper)
 
   # Padded packed rows map to vertex `n` (out of bounds): the un-permute
   # gathers through `packed_of`, which never selects them; gathers through
   # `row_of` clip and are masked.
-  row_of = np.concatenate(
-      [perm, np.full(n_pad - n, n, np.int32)]) if n_pad > n else perm
+  row_of = np.concatenate([owner_of[perm],
+                           np.full(n_pad - perm.size, n, np.int32)])
   return EllGraph(
-      n=n, width=int(width),
+      n=n, width=width,
       cols=jnp.asarray(cols), vals=jnp.asarray(vals), mask=jnp.asarray(mask),
-      row_of=jnp.asarray(row_of), packed_of=jnp.asarray(inv), spill=spill,
+      row_of=jnp.asarray(row_of), packed_of=jnp.asarray(pos[:n]),
+      split_rows=jnp.asarray(pos[n:]), split_owner=jnp.asarray(owner),
       slot_rows=slot_rows)
 
 
@@ -289,9 +309,4 @@ def coo_from_ell(g: EllGraph) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
   src = cols[ss, rr]
   dst = row_of[rr]
   w = vals[ss, rr]
-  if g.spill is not None:
-    em = np.asarray(g.spill.emask)
-    src = np.concatenate([src, np.asarray(g.spill.src)[em]])
-    dst = np.concatenate([dst, np.asarray(g.spill.dst)[em]])
-    w = np.concatenate([w, np.asarray(g.spill.w)[em]])
   return src, dst, w

@@ -16,8 +16,8 @@ Backends:
                      segmented scan for generic monoids; a segmented sort
                      for ``mode``, the one reduce that is no monoid).
   * ``spmv_ell``   — degree-sorted slot-major ELL: gather + reduce over
-                     slots — the layout consumed by the Pallas kernel; hub
-                     spill edges are folded in via the COO path.
+                     slots — the layout consumed by the Pallas kernel; the
+                     split rows of hubs are folded into their owners.
 """
 
 from __future__ import annotations
@@ -36,12 +36,12 @@ PyTree = Any
 # Named scopes of a superstep's phases, all under one root, ``graphmat/``.
 # They name the device operations of each phase in the compiled program's
 # ``op_name`` metadata, and so in a profile; they add no work.  Where phases
-# nest (the spill's COO pass), the outermost names the operation.
+# nest, the outermost names the operation.
 SCOPE_SEND = "graphmat/send"
 SCOPE_GATHER = "graphmat/spmv/gather"
 SCOPE_KERNEL = "graphmat/spmv/kernel"
 SCOPE_UNPERMUTE = "graphmat/spmv/unpermute"
-SCOPE_SPILL = "graphmat/spmv/spill"
+SCOPE_SPLIT = "graphmat/spmv/split"
 SCOPE_SCATTER = "graphmat/spmv/scatter"
 SCOPE_MODE = "graphmat/spmv/mode"
 SCOPE_APPLY = "graphmat/apply"
@@ -275,7 +275,7 @@ def spmv_coo(g: graphlib.CooGraph, msg: PyTree, active: Array,
 
 
 # ---------------------------------------------------------------------------
-# ELL: gather + reduce over slots (+ spill via COO)
+# ELL: gather + reduce over slots (+ the fold of split rows)
 # ---------------------------------------------------------------------------
 
 
@@ -318,14 +318,24 @@ def _unpermute(g: graphlib.EllGraph, y_packed: PyTree, recv_packed: Array
     return _tree_gather(y_packed, g.packed_of), recv_packed[g.packed_of]
 
 
-def fold_spill(g: graphlib.EllGraph, y: PyTree, recv: Array, msg: PyTree,
-               active: Array, dst_prop: PyTree, program: GraphProgram
+def fold_split(g: graphlib.EllGraph, y: PyTree, recv: Array,
+               y_packed: PyTree, recv_packed: Array, program: GraphProgram
                ) -> Tuple[PyTree, Array]:
-  """Fold the ELL's hub spill (a COO pass) into ``(y, recv)``."""
-  if g.spill is None:
+  """Fold the packed results of the ELL's split rows into their owners'
+  ``(y, recv)``: a segment reduce of one entry per split row."""
+  if not g.split_rows.shape[0]:
     return y, recv
-  with jax.named_scope(SCOPE_SPILL):
-    y_s, recv_s = spmv_coo(g.spill, msg, active, dst_prop, program)
+  with jax.named_scope(SCOPE_SPLIT):
+    y_r = _tree_gather(y_packed, g.split_rows)
+    ident = program.identity_like(y_r)
+    if program.reduce_kind in _SCATTER_FAST:
+      y_s = _segment_reduce_fast(y_r, g.split_owner, g.n,
+                                 program.reduce_kind, ident)
+    else:
+      y_s = _segment_reduce_scan(y_r, g.split_owner, g.n,
+                                 program.reduce_fn(), ident)
+    recv_s = jnp.zeros((g.n,), jnp.bool_).at[g.split_owner].max(
+        recv_packed[g.split_rows], mode="drop", indices_are_sorted=True)
     red = program.reduce_fn()
     y = _tree_where(recv_s, _tree_where(recv, red(y, y_s), y_s), y)
     return y, recv | recv_s
@@ -337,7 +347,7 @@ def spmv_ell(g: graphlib.EllGraph, msg: PyTree, active: Array,
   y_packed, recv_packed = _ell_packed_compute(
       g, msg, active, dst_prop, program)
   y, recv = _unpermute(g, y_packed, recv_packed)
-  y, recv = fold_spill(g, y, recv, msg, active, dst_prop, program)
+  y, recv = fold_split(g, y, recv, y_packed, recv_packed, program)
   return y, (recv if with_recv else None)
 
 

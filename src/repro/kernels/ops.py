@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graph as graphlib
-from repro.core.spmv import SCOPE_GATHER, _unpermute, fold_spill
+from repro.core.spmv import SCOPE_GATHER, _unpermute, fold_split
 from repro.core.vertex_program import GraphProgram
 from repro.kernels.ell_spmv import ell_spmv_pallas
 
@@ -20,7 +20,7 @@ def spmv_ell_pallas(g: graphlib.EllGraph, msg: PyTree, active: Array,
                     dst_prop: PyTree, program: GraphProgram,
                     **kernel_kwargs) -> Tuple[PyTree, Array]:
   """Drop-in replacement for :func:`repro.core.spmv.spmv_ell` that routes the
-  packed-ELL portion through the Pallas kernel (spill still folds via COO).
+  packed-ELL rows, split rows included, through the Pallas kernel.
 
   Restrictions (enforced by ``spmv._pallas_eligible`` / asserted here):
   single-leaf scalar messages, or ``[n, Q]`` lanes of a lanewise program;
@@ -39,11 +39,11 @@ def spmv_ell_pallas(g: graphlib.EllGraph, msg: PyTree, active: Array,
     with jax.named_scope(SCOPE_GATHER):
       dpp = dp_leaves[0][jnp.minimum(g.row_of, g.n - 1)]
 
-  y_leaf, recv = ell_spmv_pallas(
+  y_leaf, recv_packed = ell_spmv_pallas(
       g.cols, g.vals, g.mask, m, active, dpp,
       process=program.process_message, reduce_kind=program.reduce_kind,
       slot_rows=g.slot_rows, **kernel_kwargs)
   y_packed = jax.tree_util.tree_unflatten(msg_def, [y_leaf])
 
-  y, recv = _unpermute(g, y_packed, recv)
-  return fold_spill(g, y, recv, msg, active, dst_prop, program)
+  y, recv = _unpermute(g, y_packed, recv_packed)
+  return fold_split(g, y, recv, y_packed, recv_packed, program)

@@ -23,7 +23,7 @@ from repro.service import GraphQueryServer, QuerySpec, SsspFamily
 from repro.service import metrics as M
 
 ELL_PHASES = {"send", "spmv/gather", "spmv/kernel", "spmv/unpermute",
-              "spmv/spill", "apply"}
+              "spmv/split", "apply"}
 COO_PHASES = {"send", "spmv/gather", "spmv/scatter", "apply"}
 # Instructions that do no work of a phase: loop plumbing and layout.
 CONTROL = {"parameter", "get-tuple-element", "tuple", "constant", "copy",
@@ -96,7 +96,7 @@ def _check_scoped(compiled_text, phases):
 def graphs(rmat_small):
   n, src, dst, w = rmat_small
   ell = G.build_ell(src, dst, w, n=n)
-  assert ell.spill is not None        # the spill's phase is on the path
+  assert len(ell.split_rows) > 0      # the split fold's phase is on the path
   return n, src, {"ell": ell, "pallas": ell,
                   "coo": G.build_coo(src, dst, w, n=n)}
 

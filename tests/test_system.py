@@ -59,7 +59,7 @@ def test_engine_terminates_on_empty_frontier(rmat_small):
 def test_backends_agree_one_superstep(rmat_small):
   n, src, dst, w = rmat_small
   coo = G.build_coo(src, dst, w, n=n)
-  ell = G.build_ell(src, dst, w, n=n, width=8)   # forces spill
+  ell = G.build_ell(src, dst, w, n=n, width=8)   # forces split rows
   adj_v, adj_s = G.dense_adjacency(src, dst, w, n=n)
   rng = np.random.default_rng(1)
   msg = jnp.asarray(rng.uniform(0, 5, n).astype(np.float32))

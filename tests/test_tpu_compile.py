@@ -22,21 +22,25 @@ from repro.kernels.ell_spmv import ell_spmv_pallas
 
 # Graph500 scale 22, edge factor 16, seed 0 (graphs.rmat_edges with
 # RMAT_PRBFS), self loops removed and deduplicated; the ELL width is
-# build_ell's default (95th-percentile in-degree) and the rest spills.
+# build_ell's default (95th-percentile in-degree).  The 32,466,159 edges of
+# rows above it fill about 254K split rows, and the partial last pieces of
+# the ~110K hub rows (5% of the rows that hold an edge) add about 90K more.
 N = 1 << 22
 EDGES = 65_622_448
 ELL_WIDTH = 128
-SPILL_EDGES = 32_466_159
+SPLIT_ROWS = 344_000
+N_PAD = -(-(N + SPLIT_ROWS) // 128) * 128
 QUERIES = 8
 HBM_BYTES = 16 * 2**30
 KERNEL_MARK = "tpu_custom_call"
 
 
 def _slot_rows():
-  """build_ell's row extents per slot, rows = 0.52 N (1 + s)^-0.7 rounded up
-  to 128: a power law fit to the exact extents at scale 20 (52% of rows hold
-  an edge, 2.3% more than 88)."""
-  return tuple(min(N, -(-int(0.52 * N * (1 + s) ** -0.7) // 128) * 128)
+  """build_ell's row extents per slot, home rows = 0.52 N (1 + s)^-0.7 (a
+  power law fit to the exact extents at scale 20: 52% of rows hold an edge,
+  2.3% more than 88) plus the split rows, rounded up to 128."""
+  return tuple(min(N_PAD, -(-int(0.52 * N * (1 + s) ** -0.7 + SPLIT_ROWS)
+                            // 128) * 128)
                for s in range(ELL_WIDTH))
 
 
@@ -100,8 +104,9 @@ def test_ell_kernel_compiles_at_scale_22(spec, lanes):
                            slot_rows=_slot_rows())
 
   compiled = jax.jit(step).lower(
-      spec((ELL_WIDTH, N), jnp.int32), spec((ELL_WIDTH, N), jnp.float32),
-      spec((ELL_WIDTH, N), jnp.bool_), spec(msg_shape, jnp.int32),
+      spec((ELL_WIDTH, N_PAD), jnp.int32),
+      spec((ELL_WIDTH, N_PAD), jnp.float32),
+      spec((ELL_WIDTH, N_PAD), jnp.bool_), spec(msg_shape, jnp.int32),
       spec((N,), jnp.bool_)).compile()
   assert KERNEL_MARK in compiled.as_text()
   # Its operands are pinned to HBM, so the kernel's own time covers reading
@@ -140,10 +145,12 @@ def test_bfs_compiles_at_scale_22(spec, fmt):
     graph = _coo(spec, EDGES)
   else:
     graph = G.EllGraph(
-        N, ELL_WIDTH, spec((ELL_WIDTH, N), jnp.int32),
-        spec((ELL_WIDTH, N), jnp.float32), spec((ELL_WIDTH, N), jnp.bool_),
-        spec((N,), jnp.int32), spec((N,), jnp.int32),
-        _coo(spec, SPILL_EDGES), _slot_rows())
+        N, ELL_WIDTH, spec((ELL_WIDTH, N_PAD), jnp.int32),
+        spec((ELL_WIDTH, N_PAD), jnp.float32),
+        spec((ELL_WIDTH, N_PAD), jnp.bool_),
+        spec((N_PAD,), jnp.int32), spec((N,), jnp.int32),
+        spec((SPLIT_ROWS,), jnp.int32), spec((SPLIT_ROWS,), jnp.int32),
+        _slot_rows())
   plan = Plan(backend="coo" if fmt == "coo" else "pallas")
   compiled = jax.jit(lambda g, r: bfs(g, r, N, backend=plan)).lower(
       graph, spec((), jnp.int32)).compile()
