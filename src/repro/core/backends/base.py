@@ -34,7 +34,8 @@ class Backend:
 
   Class attributes:
     name: registry key (also the legacy string spelling).
-    container: preferred graph container — ``"dense" | "coo" | "ell"``.
+    container: preferred graph container — ``"dense" | "coo" | "ell"``, or
+      ``"dist"`` for a DistGraph on its mesh (``distributed.Mesh2dBackend``).
       Drives test-harness graph construction (a new backend declares which
       container to build and inherits the conformance suite for free).
     priority: structural-auto tie-break; higher is tried first.

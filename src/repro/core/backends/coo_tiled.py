@@ -21,8 +21,7 @@ class TiledCooBackend(base.Backend):
   priority = 70
 
   def supports(self, graph, msg, dst_prop, program):
-    return (isinstance(graph, graphlib.CooGraph)
-            and program.reduce_kind in spmv_lib._SCATTER_FAST)
+    return isinstance(graph, graphlib.CooGraph) and program.monoid is not None
 
   def eligible(self, graph, msg, dst_prop, program):
     # Profitability (tile count vs. skew) is data the tracer can't see:

@@ -38,14 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import graph as graphlib
-from repro.core.backends import base
+from repro.core.backends.pallas import kernel_takes
 from repro.core.backends.plan import Plan
 from repro.core.vertex_program import GraphProgram
-
-# Monoids with a scatter fast path (what coo_tiled and the Pallas kernel
-# can accelerate); mirrors repro.core.spmv._SCATTER_FAST / kernel support.
-_FAST_KINDS = ("add", "min", "max", "any", "all")
-_PALLAS_KINDS = ("add", "min", "max")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,16 +107,6 @@ def compute_stats(graph) -> GraphStats:
         nnz / max(graph.n * graph.n, 1), ell_width=graph.width,
         ell_efficiency=nnz / max(mask.size, 1))
   raise TypeError(f"unknown graph container {type(graph)}")
-
-
-def _pallas_shape_ok(program: Optional[GraphProgram]) -> bool:
-  """Program-level approximation of the kernel's shape eligibility (the
-  exact per-call check needs the message payload; see spmv._pallas_eligible).
-  """
-  if program is None:
-    return False
-  return (program.reduce_kind in _PALLAS_KINDS
-          and program.num_message_dims <= 1)
 
 
 class PlanCache:
@@ -197,13 +182,13 @@ class Planner:
     if stats.container == "dense":
       return Plan(backend="dense")
     if stats.container == "ell":
-      if (_pallas_shape_ok(program)
+      if (kernel_takes(program)
           and stats.ell_efficiency >= self.ell_efficiency_floor):
         return Plan(backend="pallas")
       return Plan(backend="ell")
     # COO: skewed degree distributions lose load balance in one monolithic
     # segment reduce; equal-size edge tiles restore it (paper §4.3).
-    fast = program is not None and program.reduce_kind in _FAST_KINDS
+    fast = program is not None and program.monoid is not None
     if fast and stats.hub_ratio >= self.skew_threshold:
       return Plan(backend="coo_tiled", num_tiles=self._coo_tiles(stats))
     return Plan(backend="coo")
@@ -216,7 +201,7 @@ class Planner:
       return [Plan(backend="dense")]
     if stats.container == "ell":
       out = [Plan(backend="ell")]
-      if _pallas_shape_ok(program):
+      if kernel_takes(program):
         out.append(Plan(backend="pallas"))
         n_pad = graph.n_pad
         for br in (128, 512):
@@ -228,7 +213,7 @@ class Planner:
               out.append(Plan(backend="pallas", block_queries=bq))
       return out
     out = [Plan(backend="coo")]
-    if program is None or program.reduce_kind in _FAST_KINDS:
+    if program is None or program.monoid is not None:
       t = self._coo_tiles(stats)
       for nt in sorted({t, max(2, t // 4), min(self.max_tiles, t * 4)}):
         out.append(Plan(backend="coo_tiled", num_tiles=nt))

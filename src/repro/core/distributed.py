@@ -14,8 +14,12 @@ TPU-mesh analogue:
   resharding automatically (the collective analogue of the paper's shared-
   memory reads).
 * Partial outputs are combined along "model" with a **semiring-aware
-  reduction**: ``psum``/``pmin``/``pmax`` fast-paths, all-gather + log-fold
-  for generic monoids.
+  reduction**: the monoid's collective from
+  :data:`repro.core.semiring.MONOIDS` (``psum``/``pmin``/``pmax``), an
+  all-gather + fold for generic monoids.
+* The runners are the engine's own loops and superstep; a registered
+  backend for a DistGraph on its mesh puts :func:`spmv_2d` in the SpMV's
+  place.
 * Load balance: blocks are padded to the global max block population — the
   static-shape analogue of over-partitioning; the degree-randomizing vertex
   shuffle in ``repro.graphs.partition`` keeps the max/mean ratio near 1.
@@ -29,7 +33,6 @@ superstep-boundary reshard crosses pods once per iteration.
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Any, Optional, Sequence, Tuple
 
@@ -38,6 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import backends
+from repro.core import engine
 from repro.core import graph as graphlib
 from repro.core import spmv as spmv_lib
 from repro.core.backends.plan import AUTO_PLAN, PlanLike, as_plan
@@ -127,28 +132,14 @@ def partition_2d(src, dst, w=None, *, n: int, R: int, C: int,
                    w=put(bw), emask=put(bmask))
 
 
-def _refuse_mode(program: GraphProgram) -> None:
-  """The column blocks' partial results can only be combined by a monoid."""
-  if program.reduce_kind == "mode":
-    raise ValueError(f"program {program.name!r}: the 2-D runners cannot "
-                     "combine a mode reduce across column blocks")
-
-
 def _semiring_axis_reduce(y: PyTree, recv: Array, axis_name: str,
                           program: GraphProgram) -> Tuple[PyTree, Array]:
-  kind = program.reduce_kind
-  if kind == "add":
-    y = jax.tree_util.tree_map(partial(jax.lax.psum, axis_name=axis_name), y)
-  elif kind == "min":
-    y = jax.tree_util.tree_map(partial(jax.lax.pmin, axis_name=axis_name), y)
-  elif kind == "max":
-    y = jax.tree_util.tree_map(partial(jax.lax.pmax, axis_name=axis_name), y)
-  elif kind in ("any", "all"):
-    red = jax.lax.pmax if kind == "any" else jax.lax.pmin
-    y = jax.tree_util.tree_map(
-        lambda x: red(x.astype(jnp.int8), axis_name=axis_name).astype(x.dtype),
-        y)
-  else:  # generic monoid: all-gather along the axis and fold locally.
+  """Combine the column blocks' partial ``(y, recv)`` along ``axis_name``:
+  the monoid's collective, or for a generic monoid an all-gather and a fold."""
+  monoid = program.monoid
+  if monoid is not None:
+    y = jax.tree_util.tree_map(lambda x: monoid.collective(x, axis_name), y)
+  else:
     red = program.reduce_fn()
     gathered = jax.tree_util.tree_map(
         lambda x: jax.lax.all_gather(x, axis_name=axis_name, axis=0), y)
@@ -161,6 +152,13 @@ def _semiring_axis_reduce(y: PyTree, recv: Array, axis_name: str,
   return y, recv
 
 
+def _refuse_mode(program: GraphProgram) -> None:
+  """The column blocks' partial results can only be combined by a monoid."""
+  if program.reduce_kind == "mode":
+    raise ValueError(f"program {program.name!r}: the 2-D SpMV cannot "
+                     "combine a mode reduce across column blocks")
+
+
 def spmv_2d(g: DistGraph, msg: PyTree, active: Array, dst_prop: PyTree,
             program: GraphProgram, mesh: Mesh,
             row_axes: Sequence[str] = ("data",),
@@ -170,18 +168,28 @@ def spmv_2d(g: DistGraph, msg: PyTree, active: Array, dst_prop: PyTree,
 
   Shardings (global view):
     * graph blocks: ``P(row_axes, col_axis)`` on the two leading dims,
-    * ``msg``/``active``: ``P(col_axis)`` (column-sharded sources),
+    * ``msg``/``active``: resharded here to ``P(col_axis)`` (column-sharded
+      sources; the superstep-boundary transpose),
     * ``dst_prop`` and outputs: ``P(row_axes)`` (row-sharded destinations).
+
+  A leaf's axes past the vertex axis (the batched engine's query axis) are
+  not sharded: each device's block SpMV grows a payload axis.
 
   ``backend`` plans the *per-device block* SpMV.  Blocks are COO, so valid
   plans are ``coo`` (default under auto) and ``coo_tiled`` — the latter
   nests the paper's partitions-≫-threads edge tiling *inside* each device
-  block on top of the 2-D mesh partitioning.
+  block on top of the 2-D mesh partitioning.  The column blocks' partial
+  results are combined by a monoid, so a ``mode`` program is refused.
   """
+  _refuse_mode(program)
   row = tuple(row_axes)
   rows_spec = row if len(row) > 1 else row[0]
   nr = g.rows_per_block
   plan = as_plan(backend)
+  col_sharding = NamedSharding(mesh, P(col_axis))
+  msg = jax.tree_util.tree_map(
+      lambda x: jax.lax.with_sharding_constraint(x, col_sharding), msg)
+  active = jax.lax.with_sharding_constraint(active, col_sharding)
 
   def local(bsrc, bdst, bw, bemask, msg_blk, act_blk, prop_blk):
     # shard_map hands us [1, 1, Eb] block slices — drop the unit block dims.
@@ -205,6 +213,39 @@ def spmv_2d(g: DistGraph, msg: PyTree, active: Array, dst_prop: PyTree,
   return f(g.src, g.dst, g.w, g.emask, msg, active, dst_prop)
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=["g"],
+         meta_fields=["mesh", "row_axes", "col_axis"])
+@dataclasses.dataclass(frozen=True)
+class _OnMesh:
+  """A :class:`DistGraph` with the mesh layout :func:`spmv_2d` runs it on:
+  the container through which the engine's superstep reaches the 2-D SpMV."""
+
+  g: DistGraph
+  mesh: Mesh
+  row_axes: Tuple[str, ...]
+  col_axis: str
+
+
+class Mesh2dBackend(backends.Backend):
+  """The 2-D SpMV over a mesh; the plan it is given plans the block SpMV."""
+
+  name = "mesh_2d"
+  container = "dist"
+  priority = 50    # the only backend that runs the container
+
+  def supports(self, graph, msg, dst_prop, program):
+    return isinstance(graph, _OnMesh)
+
+  def execute(self, graph, msg, active, dst_prop, program, plan, with_recv):
+    y, recv = spmv_2d(graph.g, msg, active, dst_prop, program, graph.mesh,
+                      row_axes=graph.row_axes, col_axis=graph.col_axis,
+                      backend=plan)
+    return y, (recv if with_recv else None)
+
+
+backends.register(Mesh2dBackend())
+
+
 def pad_vertex_tree(tree: PyTree, n: int, n_pad: int, fill=0) -> PyTree:
   """Pad leading vertex axis from n to n_pad with ``fill``."""
   if n_pad == n:
@@ -215,56 +256,45 @@ def pad_vertex_tree(tree: PyTree, n: int, n_pad: int, fill=0) -> PyTree:
   return jax.tree_util.tree_map(padleaf, tree)
 
 
+def _run_on_mesh(run, g: DistGraph, program: GraphProgram, init_prop: PyTree,
+                 init_active: Array, mesh: Mesh, max_iters: int,
+                 row_axes: Sequence[str], col_axis: str, backend: PlanLike):
+  """``run`` (an engine loop) over ``g`` on ``mesh``, under one jit.
+
+  The graph is a jit argument, so its blocks stay where partition_2d put
+  them instead of being baked into the program as constants.  A ``mode``
+  program is refused before tracing: the batched SEND would first miss its
+  inert message.
+  """
+  _refuse_mode(program)
+  plan = as_plan(backend)
+
+  @jax.jit
+  def loop(on_mesh, prop0, active0):
+    return run(on_mesh, program, prop0, active0, max_iters=max_iters,
+               backend=plan)
+
+  return loop(_OnMesh(g, mesh, tuple(row_axes), col_axis), init_prop,
+              init_active)
+
+
 def run_graph_program_2d(
     g: DistGraph, program: GraphProgram, init_prop: PyTree,
     init_active: Array, mesh: Mesh, *,
     max_iters: int = 0x7FFFFFF0,
     row_axes: Sequence[str] = ("data",), col_axis: str = "model",
     backend: PlanLike = AUTO_PLAN):
-  """Distributed Algorithm 2: the full superstep loop under one jit.
+  """Distributed Algorithm 2: the engine's loop
+  (:func:`repro.core.engine.run_graph_program`) with :func:`spmv_2d` as its
+  SpMV, under one jit.
 
   ``init_prop``/``init_active`` must already be padded to ``g.n_pad``.
-  Returns the final (prop, active, iteration, num_active) like the local
-  engine.
+  ``backend`` plans the block SpMV.  Returns the final
+  :class:`~repro.core.engine.EngineState`, as the local engine does.
   """
-  from repro.core.engine import EngineState  # circular-import dodge
-
-  _refuse_mode(program)
-  row = tuple(row_axes)
-  rows_spec = row if len(row) > 1 else row[0]
-  plan = as_plan(backend)
-  prop_sharding = NamedSharding(mesh, P(rows_spec))
-  col_sharding = NamedSharding(mesh, P(col_axis))
-
-  def constrain(tree, sharding):
-    return jax.tree_util.tree_map(
-        lambda x: jax.lax.with_sharding_constraint(x, sharding), tree)
-
-  def superstep(g: DistGraph, state: EngineState) -> EngineState:
-    msg = jax.vmap(program.send_message)(state.prop)
-    # Reshard sources column-wise (the superstep-boundary transpose).
-    msg = constrain(msg, col_sharding)
-    act = jax.lax.with_sharding_constraint(state.active, col_sharding)
-    y, recv = spmv_2d(g, msg, act, state.prop, program, mesh,
-                      row_axes=row, col_axis=col_axis, backend=plan)
-    new_prop = jax.vmap(program.apply)(y, state.prop)
-    new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
-    new_prop = constrain(new_prop, prop_sharding)
-    changed = jnp.logical_and(recv, program.activate(state.prop, new_prop))
-    return EngineState(new_prop, changed, state.iteration + 1,
-                       jnp.sum(changed.astype(jnp.int32)))
-
-  # The graph is a jit argument, so its blocks stay where partition_2d put
-  # them instead of being baked into the program as constants.
-  @jax.jit
-  def loop(g, prop0, active0):
-    state = EngineState(prop0, active0, jnp.int32(0),
-                        jnp.sum(active0.astype(jnp.int32)))
-    return jax.lax.while_loop(
-        lambda s: jnp.logical_and(s.iteration < max_iters, s.num_active > 0),
-        partial(superstep, g), state)
-
-  return loop(g, init_prop, init_active)
+  return _run_on_mesh(engine.run_graph_program, g, program, init_prop,
+                      init_active, mesh, max_iters, row_axes, col_axis,
+                      backend)
 
 
 def run_graph_program_2d_batched(
@@ -273,7 +303,9 @@ def run_graph_program_2d_batched(
     max_iters: int = 0x7FFFFFF0,
     row_axes: Sequence[str] = ("data",), col_axis: str = "model",
     backend: PlanLike = AUTO_PLAN):
-  """Distributed batched multi-query loop (SpMM over the 2-D mesh).
+  """Distributed batched multi-query loop: the engine's
+  :func:`repro.core.engine.run_batched` with :func:`spmv_2d` as its SpMV
+  (an SpMM over the 2-D mesh).
 
   The query axis (dim 1 of every leaf, ``[n_pad, Q, ...]``) is carried
   *unsharded* through the 2-D block partitioning: ``P(col)``/``P(row)``
@@ -284,54 +316,5 @@ def run_graph_program_2d_batched(
   Requires a batched-ready program (``inert_message`` set, per-lane
   ``activate``).  Returns the final :class:`BatchedEngineState`.
   """
-  from repro.core.engine import BatchedEngineState, init_batched_state
-
-  _refuse_mode(program)
-  row = tuple(row_axes)
-  rows_spec = row if len(row) > 1 else row[0]
-  plan = as_plan(backend)
-  prop_sharding = NamedSharding(mesh, P(rows_spec))
-  col_sharding = NamedSharding(mesh, P(col_axis))
-
-  def constrain(tree, sharding):
-    return jax.tree_util.tree_map(
-        lambda x: jax.lax.with_sharding_constraint(x, sharding), tree)
-
-  def superstep(g: DistGraph, state: BatchedEngineState
-                ) -> BatchedEngineState:
-    live = jnp.logical_not(state.done)
-    msg = jax.vmap(program.send_message)(state.prop)
-    lane_mask = jnp.logical_and(state.active, live[None, :])
-    msg = spmv_lib.mask_inert(msg, lane_mask, program)
-    # Reshard sources column-wise (P only constrains the vertex axis; the
-    # query axis stays replicated along "model").
-    msg = constrain(msg, col_sharding)
-    vert_active = jax.lax.with_sharding_constraint(
-        jnp.any(lane_mask, axis=1), col_sharding)
-    y, recv = spmv_2d(g, msg, vert_active, state.prop, program, mesh,
-                      row_axes=row, col_axis=col_axis, backend=plan)
-    new_prop = jax.vmap(program.apply)(y, state.prop)
-    if program.needs_recv:
-      new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
-      changed = jnp.logical_and(recv[:, None],
-                                program.activate(state.prop, new_prop))
-    else:
-      changed = program.activate(state.prop, new_prop)
-    new_prop = constrain(new_prop, prop_sharding)
-    changed = jnp.logical_and(changed, live[None, :])
-    num_active = jnp.sum(changed.astype(jnp.int32), axis=0)
-    return BatchedEngineState(
-        prop=new_prop, active=changed, iteration=state.iteration + 1,
-        done=jnp.logical_or(state.done, num_active == 0),
-        num_active=num_active,
-        iters=state.iters + live.astype(jnp.int32))
-
-  @jax.jit
-  def loop(g, prop0, active0):  # the graph is an argument, as above
-    state = init_batched_state(prop0, active0)
-    return jax.lax.while_loop(
-        lambda s: jnp.logical_and(s.iteration < max_iters,
-                                  jnp.logical_not(jnp.all(s.done))),
-        partial(superstep, g), state)
-
-  return loop(g, init_prop, init_active)
+  return _run_on_mesh(engine.run_batched, g, program, init_prop, init_active,
+                      mesh, max_iters, row_axes, col_axis, backend)

@@ -12,8 +12,7 @@ fixed-shape pytrees, so there is no retracing across supersteps.
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,25 +32,49 @@ class EngineState(NamedTuple):
   num_active: Array      # int32 scalar (for stats / convergence)
 
 
-def _superstep(graph, program: GraphProgram, state: EngineState,
-               plan: Plan) -> EngineState:
+def _send_spmv_apply(graph, program: GraphProgram, prop: PyTree,
+                     active: Array, plan: Plan, live: Optional[Array] = None
+                     ) -> Tuple[PyTree, Array]:
+  """SEND, the generalized SpMV and APPLY of one superstep, for either state
+  shape: returns the new properties and the raw next frontier.
+
+  ``live`` (the batched body's ``bool[Q]`` of unretired columns) folds the
+  per-query frontier ``active[n, Q]`` into the payload: inactive lanes, and
+  whole retired columns, send the inert message, and the SpMV's bitvector is
+  the OR over the lanes.
+  """
   # SEND_MESSAGE for active vertices (vectorized; inactive lanes annihilated
   # inside the SpMV by the active mask).
   with jax.named_scope(spmv_lib.SCOPE_SEND):
-    msg = jax.vmap(program.send_message)(state.prop)
+    msg = jax.vmap(program.send_message)(prop)
+    if live is not None:
+      lane_mask = jnp.logical_and(active, live[None, :])
+      msg = spmv_lib.mask_inert(msg, lane_mask, program)
+      active = jnp.any(lane_mask, axis=1)
   # Generalized SpMV: PROCESS_MESSAGE ⊗ / REDUCE ⊕.
-  y, recv = spmv_lib.spmv(graph, msg, state.active, state.prop, program,
-                          backend=plan, with_recv=program.needs_recv)
+  y, recv = spmv_lib.spmv(graph, msg, active, prop, program, backend=plan,
+                          with_recv=program.needs_recv)
   # APPLY for vertices that received a message.  Monotone programs
   # (needs_recv=False) apply unconditionally: APPLY(identity, old) == old,
   # so the receive mask and its E-sized scatter are skipped entirely.
   with jax.named_scope(spmv_lib.SCOPE_APPLY):
-    new_prop = jax.vmap(program.apply)(y, state.prop)
-    if program.needs_recv:
-      new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
-      changed = jnp.logical_and(recv, program.activate(state.prop, new_prop))
-    else:
-      changed = program.activate(state.prop, new_prop)
+    new_prop = jax.vmap(program.apply)(y, prop)
+    if not program.needs_recv:
+      return new_prop, program.activate(prop, new_prop)
+    # recv is per vertex (any query delivered); per-lane correctness relies
+    # on the inert-message contract: untouched lanes see an identity-reduced
+    # input and APPLY must leave them unchanged (see GraphProgram docs).
+    new_prop = spmv_lib._tree_where(recv, new_prop, prop)
+    if live is not None:
+      recv = recv[:, None]
+    return new_prop, jnp.logical_and(recv, program.activate(prop, new_prop))
+
+
+def _superstep(graph, program: GraphProgram, state: EngineState,
+               plan: Plan) -> EngineState:
+  new_prop, changed = _send_spmv_apply(graph, program, state.prop,
+                                       state.active, plan)
+  with jax.named_scope(spmv_lib.SCOPE_APPLY):
     return EngineState(
         prop=new_prop,
         active=changed,
@@ -68,26 +91,24 @@ def run_graph_program(
     *,
     max_iters: int = 0x7FFFFFF0,
     backend: PlanLike = AUTO_PLAN,
-    unroll_first: bool = False,
 ) -> EngineState:
   """Run ``program`` on ``graph`` until convergence (paper's Algorithm 2).
 
   Args:
-    graph: a CooGraph or EllGraph (already partitioned/packed).
+    graph: a container some registered backend runs: a DenseGraph,
+      CooGraph or EllGraph, or a DistGraph on its mesh
+      (:func:`repro.core.distributed.run_graph_program_2d`).
     init_prop: vertex-property pytree, leaves ``[n, ...]``.
     init_active: ``bool[n]`` initial frontier.
     max_iters: superstep cap (-1 semantics of the paper = "huge").
     backend: execution plan — a :class:`repro.core.backends.Plan`, a
       registered backend name (legacy string shim), or None/"auto".
-    unroll_first: trace one superstep eagerly first (debugging aid).
 
   Returns the final :class:`EngineState`.
   """
   plan = as_plan(backend)
   n_active0 = jnp.sum(init_active.astype(jnp.int32))
   state = EngineState(init_prop, init_active, jnp.int32(0), n_active0)
-  if unroll_first:
-    state = _superstep(graph, program, state, plan)
 
   def cond(s: EngineState):
     return jnp.logical_and(s.iteration < max_iters, s.num_active > 0)
@@ -149,26 +170,9 @@ def _batched_superstep(graph, program: GraphProgram,
                        plan: Plan) -> BatchedEngineState:
   with jax.named_scope(spmv_lib.SCOPE_SEND):
     live = jnp.logical_not(state.done)
-    msg = jax.vmap(program.send_message)(state.prop)    # leaves [n, Q, ...]
-    # Fold the per-query frontier into the payload: inactive lanes (and
-    # whole retired columns) send the inert message.
-    lane_mask = jnp.logical_and(state.active, live[None, :])
-    msg = spmv_lib.mask_inert(msg, lane_mask, program)
-    vert_active = jnp.any(lane_mask, axis=1)            # bool[n] bitvector
-  y, recv = spmv_lib.spmv(graph, msg, vert_active, state.prop, program,
-                          backend=plan, with_recv=program.needs_recv)
+  new_prop, changed = _send_spmv_apply(graph, program, state.prop,
+                                       state.active, plan, live)
   with jax.named_scope(spmv_lib.SCOPE_APPLY):
-    new_prop = jax.vmap(program.apply)(y, state.prop)
-    if program.needs_recv:
-      # recv is per-vertex (any query delivered); per-lane correctness
-      # relies on the inert-message contract — untouched lanes see an
-      # identity-reduced input and APPLY must leave them unchanged (see
-      # GraphProgram docs).
-      new_prop = spmv_lib._tree_where(recv, new_prop, state.prop)
-      changed = jnp.logical_and(recv[:, None],
-                                program.activate(state.prop, new_prop))
-    else:
-      changed = program.activate(state.prop, new_prop)
     changed = jnp.logical_and(changed, live[None, :])   # retired stay dead
     num_active = jnp.sum(changed.astype(jnp.int32), axis=0)
     return BatchedEngineState(
@@ -193,7 +197,8 @@ def run_batched(
   """Run Q batched queries of ``program`` until every column converges.
 
   Args:
-    graph: a DenseGraph, CooGraph or EllGraph.
+    graph: a container some registered backend runs (see
+      :func:`run_graph_program`).
     init_prop: vertex-property pytree, leaves ``[n, Q, ...]``.
     init_active: ``bool[n, Q]`` initial per-query frontiers.
     max_iters: global superstep cap.

@@ -3,8 +3,11 @@
 GraphMat (Sundaram et al., 2015) maps vertex programs onto a *generalized*
 SpMV in which the semiring multiply is replaced by the user's
 ``PROCESS_MESSAGE`` and the semiring add by the user's ``REDUCE``.  This
-module provides the algebraic core: a :class:`Semiring` value object plus the
-standard instances used by the paper's five algorithms.
+module provides the algebraic core: the table of built-in reduce kinds
+(:data:`MONOIDS`: identity, combine, axis reduce, scatter and mesh
+collective of each, and whether the Pallas kernel takes it), which every
+backend reads, and a :class:`Semiring` value object with the standard
+instances used by the paper's five algorithms.
 
 The ``reduce`` operation must be associative and commutative (the paper makes
 the same requirement) — this is what lets the backend parallelize the
@@ -17,6 +20,7 @@ by a segmented sort of the arriving messages.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -24,38 +28,67 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
-# Reduction kinds with hardware fast-paths.  ``generic`` falls back to a
-# segmented associative scan (still parallel, but no scatter fast-path).
-# ``mode`` is not a monoid (see the module docstring).
-REDUCE_KINDS = ("add", "min", "max", "any", "all", "generic", "mode")
+
+def _extreme(dtype, top: bool) -> Array:
+  """The dtype's largest (``top``) or smallest value; ±inf for floats."""
+  if jnp.issubdtype(dtype, jnp.floating):
+    return jnp.array(jnp.inf if top else -jnp.inf, dtype)
+  info = jnp.iinfo(dtype)
+  return jnp.array(info.max if top else info.min, dtype)
 
 
-def _identity_for(kind: str, dtype) -> Any:
-  if kind == "add":
-    return jnp.zeros((), dtype)
-  if kind == "min":
-    if jnp.issubdtype(dtype, jnp.floating):
-      return jnp.array(jnp.inf, dtype)
-    return jnp.array(jnp.iinfo(dtype).max, dtype)
-  if kind == "max":
-    if jnp.issubdtype(dtype, jnp.floating):
-      return jnp.array(-jnp.inf, dtype)
-    return jnp.array(jnp.iinfo(dtype).min, dtype)
-  if kind == "any":
-    return jnp.zeros((), jnp.bool_)
-  if kind == "all":
-    return jnp.ones((), jnp.bool_)
-  raise ValueError(f"no default identity for reduce kind {kind!r}")
+def _through_int8(collective):
+  """A mesh collective of bool leaves, taken in int8."""
+  return lambda x, axis_name: collective(
+      x.astype(jnp.int8), axis_name=axis_name).astype(x.dtype)
 
 
-def reduce_fn_for(kind: str) -> Callable[[Array, Array], Array]:
-  return {
-      "add": jnp.add,
-      "min": jnp.minimum,
-      "max": jnp.maximum,
-      "any": jnp.logical_or,
-      "all": jnp.logical_and,
-  }[kind]
+@dataclasses.dataclass(frozen=True)
+class Monoid:
+  """One built-in reduce kind, as every path that runs it needs it.
+
+  Attributes:
+    identity: ``dtype -> scalar`` identity (``bool`` for any/all).
+    combine: the binary combine of two arrays.
+    reduce: the reduce over an axis, called as ``reduce(x, axis=...)``.
+    scatter: the ``.at[]`` method that combines values into an array.
+    collective: ``(x, axis_name) -> x``, the reduce across a mesh axis.
+    pallas: whether the Pallas ELL kernel takes the kind.
+  """
+
+  identity: Callable[[Any], Array]
+  combine: Callable[[Array, Array], Array]
+  reduce: Callable[..., Array]
+  scatter: str
+  collective: Callable[[Array, str], Array]
+  pallas: bool
+
+
+# The reduce kinds that are monoids with hardware fast paths.  ``generic``
+# (a user monoid, ``GraphProgram.reduce``) takes a segmented associative
+# scan instead, and ``mode`` is no monoid (see the module docstring).
+MONOIDS = {
+    "add": Monoid(lambda dtype: jnp.zeros((), dtype), jnp.add, jnp.sum,
+                  "add", jax.lax.psum, pallas=True),
+    "min": Monoid(partial(_extreme, top=True), jnp.minimum, jnp.min, "min",
+                  jax.lax.pmin, pallas=True),
+    "max": Monoid(partial(_extreme, top=False), jnp.maximum, jnp.max, "max",
+                  jax.lax.pmax, pallas=True),
+    "any": Monoid(lambda dtype: jnp.zeros((), jnp.bool_), jnp.logical_or,
+                  jnp.any, "max", _through_int8(jax.lax.pmax), pallas=False),
+    "all": Monoid(lambda dtype: jnp.ones((), jnp.bool_), jnp.logical_and,
+                  jnp.all, "min", _through_int8(jax.lax.pmin), pallas=False),
+}
+REDUCE_KINDS = (*MONOIDS, "generic", "mode")
+
+
+def monoid(kind: str) -> Monoid:
+  """The table entry of a built-in monoid kind."""
+  try:
+    return MONOIDS[kind]
+  except KeyError:
+    raise ValueError(f"reduce kind {kind!r} is no built-in monoid: it has "
+                     "no default identity or combine") from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,10 +104,10 @@ class Semiring:
   name: str
   add: Callable[[Array, Array], Array]
   mul: Callable[[Array, Array], Array]
-  reduce_kind: str  # one of REDUCE_KINDS; used to pick scatter fast-paths.
+  reduce_kind: str  # a key of MONOIDS
 
   def identity(self, dtype) -> Array:
-    return _identity_for(self.reduce_kind, dtype)
+    return monoid(self.reduce_kind).identity(dtype)
 
   def __repr__(self) -> str:  # pragma: no cover - cosmetic
     return f"Semiring({self.name})"

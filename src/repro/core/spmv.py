@@ -48,11 +48,6 @@ SCOPE_APPLY = "graphmat/apply"
 SCOPE_INSTALL = "graphmat/install"
 SCOPE_EXTRACT = "graphmat/extract"
 
-_SCATTER_FAST = {"add", "min", "max", "any", "all"}
-_AXIS_RED = {"add": jnp.sum, "min": jnp.min, "max": jnp.max,
-             "any": jnp.any, "all": jnp.all}
-
-
 def _tree_gather(tree: PyTree, idx: Array) -> PyTree:
   """Gather rows ``tree[idx]`` per leaf (idx may be multi-dimensional)."""
   return jax.tree_util.tree_map(lambda x: x[idx], tree)
@@ -91,12 +86,19 @@ def _vmap_process(program: GraphProgram, batch_dims: int):
   return f
 
 
-def _axis_tree_reduce(tree: PyTree, red, ident: PyTree, axis: int) -> PyTree:
-  """Reduce ``axis`` with a tree-level binary monoid (halving, log₂ steps).
+def _reduce_axis(tree: PyTree, program: GraphProgram, ident: PyTree,
+                 axis: int) -> PyTree:
+  """REDUCE over ``axis``: the monoid's axis reduce, or for a generic monoid
+  its binary ``reduce`` in a halving tree (log₂ steps).
 
   ``ident`` is a same-structure pytree of identity-filled arrays used to pad
   the axis to a power of two.
   """
+  if program.monoid is not None:
+    return jax.tree_util.tree_map(
+        lambda x: program.monoid.reduce(x, axis=axis), tree)
+  red = program.reduce_fn()
+
   def dim(t):
     return jax.tree_util.tree_leaves(t)[0].shape[axis]
 
@@ -143,11 +145,7 @@ def spmv_dense(adj_vals: Array, adj_struct: Array, msg: PyTree, active: Array,
   valid = adj_struct & active[None, :]
   ident = program.identity_like(r)
   r = _tree_where(valid, r, ident)
-  if program.reduce_kind in _SCATTER_FAST:
-    axis_red = _AXIS_RED[program.reduce_kind]
-    y = jax.tree_util.tree_map(lambda x: axis_red(x, axis=1), r)
-  else:
-    y = _axis_tree_reduce(r, program.reduce_fn(), ident, axis=1)
+  y = _reduce_axis(r, program, ident, axis=1)
   recv = jnp.any(valid, axis=1)
   return y, recv
 
@@ -157,24 +155,27 @@ def spmv_dense(adj_vals: Array, adj_struct: Array, msg: PyTree, active: Array,
 # ---------------------------------------------------------------------------
 
 
-def _segment_reduce_fast(r: PyTree, dst: Array, n: int, kind: str,
-                         ident: PyTree) -> PyTree:
-  """Scatter-based segment reduce for monoids with an ``.at[]`` fast path."""
+def _scatter(out: Array, idx: Array, leaf: Array, monoid) -> Array:
+  """Combine ``leaf`` into ``out[idx]`` with the monoid's ``.at[]`` method.
+
+  ``idx`` is non-decreasing (the CooGraph invariant); saying so lets XLA
+  compile a large scatter in seconds instead of tens of seconds.
+  """
+  return getattr(out.at[idx], monoid.scatter)(
+      leaf, mode="drop", indices_are_sorted=True)
+
+
+def _segment_reduce(r: PyTree, dst: Array, n: int, program: GraphProgram,
+                    ident: PyTree) -> PyTree:
+  """REDUCE of the rows of ``r`` into ``y[dst]``: the monoid's scatter, or
+  for a generic monoid a segmented scan."""
+  if program.monoid is None:
+    return _segment_reduce_scan(r, dst, n, program.reduce_fn(), ident)
   # Identity leaves are full arrays shaped like r; take their scalar fill.
-  # ``dst`` is non-decreasing (the CooGraph invariant); saying so lets XLA
-  # compile a large scatter in seconds instead of tens of seconds.
   def scatter(leaf, ident_leaf):
     fill = ident_leaf.reshape(-1)[0]
     out = jnp.full((n,) + leaf.shape[1:], fill, leaf.dtype)
-    upd = out.at[dst]
-    kw = dict(mode="drop", indices_are_sorted=True)
-    if kind == "add":
-      return upd.add(leaf, **kw)
-    if kind in ("min", "all"):
-      return upd.min(leaf, **kw)
-    if kind in ("max", "any"):
-      return upd.max(leaf, **kw)
-    raise ValueError(kind)
+    return _scatter(out, dst, leaf, program.monoid)
   return jax.tree_util.tree_map(scatter, r, ident)
 
 
@@ -263,10 +264,7 @@ def spmv_coo(g: graphlib.CooGraph, msg: PyTree, active: Array,
     return (jax.tree_util.tree_unflatten(treedef, [y]),
             recv if with_recv else None)
   with jax.named_scope(SCOPE_SCATTER):
-    if program.reduce_kind in _SCATTER_FAST:
-      y = _segment_reduce_fast(r, g.dst, g.n, program.reduce_kind, ident)
-    else:
-      y = _segment_reduce_scan(r, g.dst, g.n, program.reduce_fn(), ident)
+    y = _segment_reduce(r, g.dst, g.n, program, ident)
     if not with_recv:
       return y, None
     recv = jnp.zeros((g.n,), jnp.bool_).at[g.dst].max(
@@ -301,11 +299,7 @@ def _ell_packed_compute(g: graphlib.EllGraph, msg: PyTree, active: Array,
     ident = program.identity_like(r)
     r = _tree_where(valid, r, ident)
   with jax.named_scope(SCOPE_KERNEL):
-    if program.reduce_kind in _SCATTER_FAST:
-      axis_red = _AXIS_RED[program.reduce_kind]
-      y_packed = jax.tree_util.tree_map(lambda x: axis_red(x, axis=0), r)
-    else:
-      y_packed = _axis_tree_reduce(r, program.reduce_fn(), ident, axis=0)
+    y_packed = _reduce_axis(r, program, ident, axis=0)
     recv_packed = jnp.any(valid, axis=0)
   return y_packed, recv_packed
 
@@ -328,12 +322,7 @@ def fold_split(g: graphlib.EllGraph, y: PyTree, recv: Array,
   with jax.named_scope(SCOPE_SPLIT):
     y_r = _tree_gather(y_packed, g.split_rows)
     ident = program.identity_like(y_r)
-    if program.reduce_kind in _SCATTER_FAST:
-      y_s = _segment_reduce_fast(y_r, g.split_owner, g.n,
-                                 program.reduce_kind, ident)
-    else:
-      y_s = _segment_reduce_scan(y_r, g.split_owner, g.n,
-                                 program.reduce_fn(), ident)
+    y_s = _segment_reduce(y_r, g.split_owner, g.n, program, ident)
     recv_s = jnp.zeros((g.n,), jnp.bool_).at[g.split_owner].max(
         recv_packed[g.split_rows], mode="drop", indices_are_sorted=True)
     red = program.reduce_fn()
@@ -385,12 +374,13 @@ def spmv_coo_tiled(g: graphlib.CooGraph, msg: PyTree, active: Array,
   single scatter (ascending edge order from the identity), so results are
   bitwise-equal to the untiled COO backend.
 
-  Requires a scatter-fast monoid (add/min/max/any/all); generic monoids fall
-  back to :func:`spmv_coo` at dispatch (see the registry's ``supports``).
+  Requires a built-in monoid (:data:`repro.core.semiring.MONOIDS`); generic
+  monoids fall back to :func:`spmv_coo` at dispatch (see the registry's
+  ``supports``).
   """
-  if program.reduce_kind not in _SCATTER_FAST:
+  if program.monoid is None:
     raise ValueError(
-        f"spmv_coo_tiled requires a scatter-fast reduce, got "
+        f"spmv_coo_tiled requires a built-in monoid, got "
         f"{program.reduce_kind!r}")
   cap = g.capacity
   t = int(num_tiles) if num_tiles else default_num_tiles(cap)
@@ -424,17 +414,6 @@ def spmv_coo_tiled(g: graphlib.CooGraph, msg: PyTree, active: Array,
   y0 = program.identity_like(proto)
   recv0 = jnp.zeros((g.n,), jnp.bool_) if with_recv else None
 
-  kind = program.reduce_kind
-
-  def scatter(acc, idx, leaf):  # a tile of dst-sorted edges
-    upd = acc.at[idx]
-    kw = dict(mode="drop", indices_are_sorted=True)
-    if kind == "add":
-      return upd.add(leaf, **kw)
-    if kind in ("min", "all"):
-      return upd.min(leaf, **kw)
-    return upd.max(leaf, **kw)  # max / any
-
   def body(i, carry):
     y, recv = carry
     s_t, d_t, w_t, m_t = src[i], dst[i], w[i], emask[i]
@@ -447,7 +426,7 @@ def spmv_coo_tiled(g: graphlib.CooGraph, msg: PyTree, active: Array,
     valid = m_t & active[s_t]
     r = _tree_where(valid, r, program.identity_like(r))
     y = jax.tree_util.tree_map(
-        lambda acc, leaf: scatter(acc, d_t, leaf), y, r)
+        lambda acc, leaf: _scatter(acc, d_t, leaf, program.monoid), y, r)
     if recv is not None:
       recv = recv.at[d_t].max(valid, mode="drop", indices_are_sorted=True)
     return y, recv
@@ -477,18 +456,3 @@ def spmv(graph, msg: PyTree, active: Array, dst_prop: PyTree,
   impl = backends_lib.resolve(plan, graph, msg, dst_prop, program)
   return impl.execute(graph, msg, active, dst_prop, program, plan, with_recv)
 
-
-def _pallas_eligible(g: graphlib.EllGraph, msg: PyTree, dst_prop: PyTree,
-                     program: GraphProgram) -> bool:
-  # The Pallas kernel handles single-leaf scalar messages, or [n, Q] lanes of
-  # a lanewise program, with add/min/max reductions; everything else uses
-  # the jnp ELL backend.
-  leaves = jax.tree_util.tree_leaves(msg)
-  if len(leaves) != 1 or program.reduce_kind not in ("add", "min", "max"):
-    return False
-  ndim = leaves[0].ndim
-  if ndim > 2 or (ndim == 2 and not program.lanewise):
-    return False
-  dp_leaves = jax.tree_util.tree_leaves(dst_prop)
-  return (not program.process_reads_dst) or (
-      len(dp_leaves) == 1 and dp_leaves[0].ndim <= ndim)

@@ -78,8 +78,9 @@ class GraphProgram:
 
   Attributes:
     process_message: ``(message, edge_value, dst_property) -> result``.
-    reduce_kind: one of :data:`repro.core.semiring.REDUCE_KINDS`.  Fast
-      scatter paths exist for add/min/max/any/all; ``generic`` uses a
+    reduce_kind: one of :data:`repro.core.semiring.REDUCE_KINDS`.  The
+      built-in monoids (:data:`repro.core.semiring.MONOIDS`) have fast
+      scatter paths; ``generic`` uses a
       segmented associative scan and requires ``reduce``.  ``mode`` is not
       associative: the result is the value that arrives most often, ties
       to the smallest.  It takes integer scalar messages only, has no
@@ -142,12 +143,16 @@ class GraphProgram:
 
   # -- derived helpers -------------------------------------------------------
 
+  @property
+  def monoid(self) -> Optional[sr.Monoid]:
+    """The reduce kind's entry of :data:`repro.core.semiring.MONOIDS`; None
+    for ``generic`` and ``mode``."""
+    return sr.MONOIDS.get(self.reduce_kind)
+
   def reduce_fn(self) -> Callable[[PyTree, PyTree], PyTree]:
-    if self.reduce_kind == "mode":
-      raise ValueError(f"program {self.name!r}: mode has no binary reduce")
     if self.reduce is not None:
       return self.reduce
-    leaf = sr.reduce_fn_for(self.reduce_kind)
+    leaf = sr.monoid(self.reduce_kind).combine
     return lambda a, b: jax.tree_util.tree_map(leaf, a, b)
 
   def identity_like(self, result_tree: PyTree) -> PyTree:
@@ -155,12 +160,9 @@ class GraphProgram:
     if self.reduce_identity is not None:
       return jax.tree_util.tree_map(
           lambda x, i: jnp.full_like(x, i), result_tree, self.reduce_identity)
+    identity = sr.monoid(self.reduce_kind).identity
     return jax.tree_util.tree_map(
-        lambda x: jnp.full_like(x, sr._identity_for(self.reduce_kind, x.dtype)),
-        result_tree)
-
-  def from_semiring(self):  # pragma: no cover - convenience alias
-    raise NotImplementedError
+        lambda x: jnp.full_like(x, identity(x.dtype)), result_tree)
 
 
 def program_from_semiring(s: sr.Semiring, name: str = "") -> GraphProgram:

@@ -43,12 +43,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.semiring import MONOIDS
 from repro.core.spmv import SCOPE_GATHER, SCOPE_KERNEL
 
 Array = jax.Array
-
-_AXIS_RED = {"add": jnp.sum, "min": jnp.min, "max": jnp.max}
-_COMBINE = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
 # Bytes of one gathered message chunk [BQ, CW, n_pad] (an HBM temporary; the
 # TPU gather also stages a flat copy of its indices of the same size).
@@ -58,18 +56,6 @@ TILE_BYTES = 1 << 20
 # A slot chunk ends before a slot whose row extent is below this share of
 # the chunk's first extent (the gathers fetch every slot to that extent).
 EXTENT_CUT = 7 / 8
-
-
-def _identity_scalar(kind: str, dtype):
-  if kind == "add":
-    return jnp.zeros((), dtype)
-  if kind == "min":
-    return (jnp.array(jnp.inf, dtype) if jnp.issubdtype(dtype, jnp.floating)
-            else jnp.array(jnp.iinfo(dtype).max, dtype))
-  if kind == "max":
-    return (jnp.array(-jnp.inf, dtype) if jnp.issubdtype(dtype, jnp.floating)
-            else jnp.array(jnp.iinfo(dtype).min, dtype))
-  raise ValueError(kind)
 
 
 def _kernel(m_ref, e_ref, v_ref, *refs, process, reduce_kind, out_dtype):
@@ -82,9 +68,10 @@ def _kernel(m_ref, e_ref, v_ref, *refs, process, reduce_kind, out_dtype):
        else jnp.zeros(m.shape, m.dtype))             # dst property
   valid = v_ref[...] != 0                            # [CW, BR]
   r = process(m, e, d).astype(out_dtype)
+  monoid = MONOIDS[reduce_kind]
   r = jnp.where(jnp.broadcast_to(valid[None], r.shape), r,
-                _identity_scalar(reduce_kind, out_dtype))
-  y_ref[...] = _AXIS_RED[reduce_kind](r, axis=1, keepdims=True)
+                monoid.identity(out_dtype))
+  y_ref[...] = monoid.reduce(r, axis=1, keepdims=True)
 
 
 def _pick_block(total: int, target: int, multiple: int) -> int:
@@ -189,7 +176,8 @@ def ell_spmv_pallas(
       [n_pad, K] per lane.
     process: elementwise ``(m, e, d) -> r`` on same-shaped arrays — the
       per-edge PROCESS_MESSAGE of a vertex program; traced inline.
-    reduce_kind: add | min | max.
+    reduce_kind: a kind of :data:`repro.core.semiring.MONOIDS` the kernel
+      takes (``pallas``): add | min | max.
     block_rows: rows per kernel tile (BR; a multiple of 128 on TPU, or
       n_pad).
     block_slots: most slots per gather + kernel call (CW; chunks end
@@ -211,6 +199,7 @@ def ell_spmv_pallas(
       jax.ShapeDtypeStruct((), vals.dtype),
       jax.ShapeDtypeStruct((), msg.dtype if dl is None else dl.dtype)).dtype
 
+  monoid = MONOIDS[reduce_kind]
   rows = [n_pad] * w if slot_rows is None else list(slot_rows)
   isz = lanes.dtype.itemsize
   unit = block_rows or (128 if n_pad % 128 == 0 else n_pad)
@@ -242,8 +231,7 @@ def ell_spmv_pallas(
       d_shared = None if dl is None else dl.T[:, None, :]  # [1, 1, n_pad]
 
   with jax.named_scope(SCOPE_KERNEL):
-    y = jnp.full((nb, bq, 1, n_pad),
-                 _identity_scalar(reduce_kind, out_dtype), out_dtype)
+    y = jnp.full((nb, bq, 1, n_pad), monoid.identity(out_dtype), out_dtype)
     recv = jnp.zeros((n_pad,), bool)
   for s0, s1, r in chunks:
     with jax.named_scope(SCOPE_GATHER):
@@ -268,7 +256,7 @@ def ell_spmv_pallas(
     else:
       part = jax.lax.map(lambda mb: block(mb, d_shared), m_t)
     with jax.named_scope(SCOPE_KERNEL):
-      y = y.at[..., :r].set(_COMBINE[reduce_kind](y[..., :r], part))
+      y = y.at[..., :r].set(monoid.combine(y[..., :r], part))
       recv = recv.at[:r].set(jnp.logical_or(recv[:r],
                                             jnp.any(valid, axis=0)))
   with jax.named_scope(SCOPE_KERNEL):

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graph as graphlib
+from repro.core.backends.pallas import kernel_takes
 from repro.core.spmv import SCOPE_GATHER, _unpermute, fold_split
 from repro.core.vertex_program import GraphProgram
 from repro.kernels.ell_spmv import ell_spmv_pallas
@@ -22,22 +23,22 @@ def spmv_ell_pallas(g: graphlib.EllGraph, msg: PyTree, active: Array,
   """Drop-in replacement for :func:`repro.core.spmv.spmv_ell` that routes the
   packed-ELL rows, split rows included, through the Pallas kernel.
 
-  Restrictions (enforced by ``spmv._pallas_eligible`` / asserted here):
-  single-leaf scalar messages, or ``[n, Q]`` lanes of a lanewise program;
-  add/min/max reductions.
+  Takes the calls :func:`repro.core.backends.pallas.kernel_takes` accepts.
   """
+  if not kernel_takes(program, msg, dst_prop):
+    raise ValueError(
+        f"program {program.name!r}: the Pallas kernel takes a reduce kind "
+        "whose MONOIDS entry says pallas, one message leaf ([n], or the "
+        "[n, Q] lanes of a lanewise program) and at most one destination-"
+        f"property leaf; got reduce_kind={program.reduce_kind!r}")
   msg_leaves, msg_def = jax.tree_util.tree_flatten(msg)
-  assert len(msg_leaves) == 1, "pallas path: single-leaf messages only"
   m = msg_leaves[0]
-  assert m.ndim == 1 or (m.ndim == 2 and program.lanewise), (
-      "pallas path: vector payloads must be lanes of a lanewise program")
 
   dpp = None
   if program.process_reads_dst:
-    dp_leaves = jax.tree_util.tree_leaves(dst_prop)
-    assert len(dp_leaves) == 1, "pallas path: single-leaf dst_prop only"
+    dp_leaf, = jax.tree_util.tree_leaves(dst_prop)
     with jax.named_scope(SCOPE_GATHER):
-      dpp = dp_leaves[0][jnp.minimum(g.row_of, g.n - 1)]
+      dpp = dp_leaf[jnp.minimum(g.row_of, g.n - 1)]
 
   y_leaf, recv_packed = ell_spmv_pallas(
       g.cols, g.vals, g.mask, m, active, dpp,

@@ -27,7 +27,8 @@ from repro.core import graph as G
 from repro.core import backends as B
 from repro.core.backends import plan as plan_mod
 from repro.core.backends.planner import Planner, compute_stats
-from repro.core.spmv import spmv, spmv_coo, spmv_coo_tiled
+from repro.core import semiring as sr
+from repro.core.spmv import spmv, spmv_coo, spmv_coo_tiled, spmv_dense
 from repro.algos.bfs import bfs_program
 from repro.algos.pagerank import pagerank_program
 
@@ -188,6 +189,58 @@ def test_unknown_explicit_plan_raises():
   active = jnp.ones((n,), bool)
   with pytest.raises(KeyError, match="no backend"):
     spmv(g, msg, active, None, prog, backend=B.Plan(backend="nope"))
+
+
+# -- the table of reduce kinds (core/semiring.py) -----------------------------
+
+
+@pytest.mark.parametrize("kind", sorted(sr.MONOIDS))
+def test_monoid_table_entry(kind):
+  """Each built-in monoid, read from the table, gives the dense oracle's
+  answer on the COO, jnp-ELL (split rows folded by ``combine``) and Pallas
+  paths, and through the mesh collective of a 1×1 mesh; the kernel
+  predicate follows the entry's ``pallas``."""
+  from repro.core import distributed as D
+  from repro.core.backends.pallas import kernel_takes
+  from repro.core.vertex_program import GraphProgram
+  from repro.kernels.ops import spmv_ell_pallas
+  monoid = sr.MONOIDS[kind]
+  n, src, dst, w = _random_graph(5)
+  rng = np.random.default_rng(5)
+  if monoid.identity(jnp.float32).dtype == jnp.bool_:
+    msg = jnp.asarray(rng.uniform(size=n) < 0.5)
+    process = lambda m, e, d: jnp.logical_and(m, e > 1.0)
+  else:
+    msg = jnp.asarray(rng.uniform(0.5, 2.0, n).astype(np.float32))
+    process = lambda m, e, d: m * e
+  prog = GraphProgram(process_message=process, reduce_kind=kind,
+                      process_reads_dst=False, name=f"monoid_{kind}")
+  act = jnp.asarray(rng.uniform(size=n) < 0.7)
+  ell = G.build_ell(src, dst, w, n=n, width=4)
+  assert ell.split_rows.shape[0] > 0     # the fold's combine is on the path
+  mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                           ("data", "model"))
+  paths = {
+      "coo": spmv_coo(G.build_coo(src, dst, w, n=n), msg, act, msg, prog),
+      "ell": B.get_backend("ell").execute(ell, msg, act, msg, prog,
+                                          B.Plan("ell"), True),
+      "2d": jax.jit(lambda g, m, a: D.spmv_2d(g, m, a, m, prog, mesh))(
+          D.partition_2d(src, dst, w, n=n, R=1, C=1, mesh=mesh), msg, act),
+  }
+  assert kernel_takes(prog, msg, msg) == kernel_takes(prog) == monoid.pallas
+  if monoid.pallas:
+    paths["pallas"] = spmv_ell_pallas(ell, msg, act, msg, prog)
+  else:
+    assert B.resolve(B.AUTO_PLAN, ell, msg, msg, prog).name == "ell"
+  adj_v, adj_s = G.dense_adjacency(src, dst, w, n=n)
+  y_d, r_d = spmv_dense(adj_v, adj_s, msg, act, msg, prog)
+  for path, (y, r) in paths.items():
+    np.testing.assert_array_equal(np.asarray(r), np.asarray(r_d), path)
+    if kind == "add":
+      np.testing.assert_allclose(np.asarray(y), np.asarray(y_d), rtol=1e-5,
+                                 err_msg=path)
+    else:
+      np.testing.assert_array_equal(np.asarray(y), np.asarray(y_d), path)
 
 
 # -- cross-backend conformance (all registered × five families) ---------------

@@ -14,11 +14,13 @@ import pytest
 _CHILD = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json
+import dataclasses, json
 import jax, jax.numpy as jnp, numpy as np
+from repro.algos.multi import multi_sssp_program
 from repro.core import graph as G
-from repro.core.distributed import partition_2d, run_graph_program_2d, spmv_2d
-from repro.core.engine import run_graph_program
+from repro.core.distributed import (partition_2d, run_graph_program_2d,
+                                    run_graph_program_2d_batched, spmv_2d)
+from repro.core.engine import run_batched, run_graph_program
 from repro.core.vertex_program import GraphProgram
 from repro.graphs import rmat_edges, remove_self_loops, dedupe_edges
 
@@ -51,6 +53,46 @@ for shape, axes in (((4, 2), ("data", "model")),
     ok = bool(np.allclose(np.asarray(fin.prop)[:n], np.asarray(loc.prop),
                           rtol=1e-5))
     results["x".join(map(str, shape))] = ok
+
+# The 2-D runners run the engine's superstep: with and without the receive
+# mask (needs_recv), single and batched, they match the local engine on the
+# 4x2 mesh, answers and superstep counts alike.
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+dg = partition_2d(src, dst, w, n=n, R=4, C=2)
+coo = G.build_coo(src, dst, w, n=n)
+sources = np.array([3, 77, 130, 200])
+q = len(sources)
+d0 = np.full((dg.n_pad, q), np.inf, np.float32)
+d0[sources, np.arange(q)] = 0
+a0 = np.zeros((dg.n_pad, q), bool)
+a0[sources, np.arange(q)] = True
+
+def same(got, want):
+    return bool(np.allclose(np.nan_to_num(np.asarray(got)[:n], posinf=1e30),
+                            np.nan_to_num(np.asarray(want), posinf=1e30),
+                            rtol=1e-5))
+
+for needs_recv in (False, True):
+    one = dataclasses.replace(sssp, needs_recv=needs_recv)
+    lanes = dataclasses.replace(multi_sssp_program(), needs_recv=needs_recv)
+    with jax.set_mesh(mesh):
+        fin = run_graph_program_2d(dg, one, jnp.asarray(d0[:, 0]),
+                                   jnp.asarray(a0[:, 0]), mesh, max_iters=300)
+        finb = run_graph_program_2d_batched(dg, lanes, jnp.asarray(d0),
+                                            jnp.asarray(a0), mesh,
+                                            max_iters=300)
+    loc = run_graph_program(coo, one, jnp.asarray(d0[:n, 0]),
+                            jnp.asarray(a0[:n, 0]), max_iters=300,
+                            backend="coo")
+    locb = run_batched(coo, lanes, jnp.asarray(d0[:n]), jnp.asarray(a0[:n]),
+                       max_iters=300, backend="coo")
+    results[f"single_recv={needs_recv}"] = (
+        same(fin.prop, loc.prop) and int(fin.iteration) == int(loc.iteration))
+    results[f"batched_recv={needs_recv}"] = (
+        same(finb.prop, locb.prop)
+        and np.array_equal(np.asarray(finb.iters), np.asarray(locb.iters))
+        and bool(np.asarray(finb.done).all()))
 print(json.dumps(results))
 """
 
@@ -65,7 +107,10 @@ def test_distributed_sssp_matches_local():
                        capture_output=True, text=True, timeout=900)
   assert res.returncode == 0, res.stderr[-3000:]
   results = json.loads(res.stdout.strip().splitlines()[-1])
-  assert results == {"4x2": True, "2x2x2": True}, results
+  assert results == {"4x2": True, "2x2x2": True,
+                     "single_recv=False": True, "single_recv=True": True,
+                     "batched_recv=False": True,
+                     "batched_recv=True": True}, results
 
 
 _BATCHED_CHILD = r"""

@@ -7,6 +7,7 @@ spans (``repro.service.metrics.SPAN_*``) around admission, the supersteps,
 retirement and every device-to-host fetch.
 """
 
+import dataclasses
 import glob
 import re
 
@@ -146,6 +147,33 @@ def test_batched_rounds_loop_is_scoped(graphs, backend, phases):
   fn = jax.jit(lambda gr, st: engine.run_batched_rounds(
       gr, prog, st, 4, backend=Plan(backend=backend)))
   _check_scoped(fn.lower(g[backend], state).compile().as_text(), phases)
+
+
+@pytest.mark.parametrize("runner", ["run_graph_program_2d",
+                                    "run_graph_program_2d_batched"])
+def test_2d_runner_loop_is_scoped(rmat_small, runner):
+  """The 2-D runners run the engine's superstep, so SEND and APPLY carry
+  their scopes on the mesh as well."""
+  from repro.algos.pagerank import init_prop, pagerank_program
+  from repro.core import distributed as D
+  from repro.core.vertex_program import lanewise_activate
+  n, src, dst, w = rmat_small
+  mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                           ("data", "model"))
+  g = D.partition_2d(src, dst, w, n=n, R=1, C=1, mesh=mesh)
+  prog = pagerank_program()
+  prop = init_prop(jnp.asarray(np.bincount(src, minlength=n), jnp.float32))
+  active = jnp.ones((n,), bool)
+  if runner.endswith("batched"):
+    prog = dataclasses.replace(prog, activate=lanewise_activate)
+    prop, active = jax.tree_util.tree_map(lambda x: x[:, None],
+                                          (prop, active))
+  fn = jax.jit(lambda gr, p, a: getattr(D, runner)(
+      gr, prog, p, a, mesh, max_iters=3))
+  text = fn.lower(g, prop, active).compile().as_text()
+  named = {_phase(line) for lines in _computations(text).values()
+           for line in lines}
+  assert {"send", "apply", "spmv/gather", "spmv/scatter"} <= named, named
 
 
 # ---------------------------------------------------------------------------
